@@ -132,7 +132,9 @@ def main():
             env = dict(os.environ)
             src = str(Path(__file__).resolve().parents[1] / "src")
             env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            # the clients hold no model: keep them off the chip this process
+            # already holds
+            env["JAX_PLATFORMS"] = "cpu"
             cmd = [sys.executable, "-m", "repro.launch.serve",
                    "--mode", "client", "--connect", f"{host}:{port}",
                    "--queries", "1", "--budget", "150", "--n-side", "32"]

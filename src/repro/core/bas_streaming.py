@@ -5,8 +5,8 @@ Differences from the dense path (``bas.run_bas``):
 
 * stratification uses the histogram threshold
   (``stratify.stratify_streaming_chain``, backed by the fused single-sweep
-  ``sim_sweep`` Pallas kernel with a blocked numpy fallback) — O(bins)
-  memory, **one** streaming pass over prefix blocks emitting histogram +
+  ``sim_sweep`` Pallas kernel, or a blocked numpy sweep when asked for with
+  ``use_kernel=False``) — O(bins) memory, **one** streaming pass over prefix blocks emitting histogram +
   per-block count tiles + per-row top-k; collection reads the top-k and
   rescans only blocks the tiles flag.  The chain weight factorises as
   prefix-weight x last-edge pair weight, so the kernel's per-row ``scale``

@@ -79,7 +79,7 @@ def artifact_key(
     """Content-addressed identity of a sweep artifact: the table
     fingerprints plus everything that changes the tiles' *values*
     (binning resolution, weight transform, requested sweep precision).
-    Execution details that only change the layout (kernel vs fallback,
+    Execution details that only change the layout (kernel vs numpy sweep,
     block size, top-k width) are deliberately excluded — they never change
     what a hydrated query computes, only how much a rescan can skip."""
     payload = {
@@ -102,7 +102,7 @@ class IndexArtifact:
     consumer treats them as immutable; maintenance returns a new artifact.
 
     ``precision`` is the *effective* tile precision (what the sweep
-    actually binned at — the fallback path computes fp32 even when a low
+    actually binned at — the numpy sweep computes fp32 even when a low
     precision was requested); ``precision_requested`` is what the key was
     derived from, so repeat queries with the same config keep hitting."""
 
@@ -242,7 +242,7 @@ def _sweep_rows(e_rows, e2, art: IndexArtifact, use_kernel: bool,
                 k_top: int) -> SweepInfo:
     """Sweep a row region against the full right table under the artifact's
     binning config.  Low-precision tiles must come from the kernel path
-    (the numpy fallback computes fp32, which would silently mix precisions
+    (the numpy sweep computes fp32, which would silently mix precisions
     inside one artifact); tolerance inf disables the lowp re-check — the
     build already certified this table pair."""
     info = sweep_pass(

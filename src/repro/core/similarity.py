@@ -31,7 +31,10 @@ def normalize(emb: np.ndarray) -> np.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("exponent", "floor"))
 def _pair_weights_jax(e1, e2, exponent: float, floor: float):
-    sim = jnp.dot(e1, e2.T, preferred_element_type=jnp.float32)
+    # HIGHEST: a TPU runs a default-precision f32 dot as one bf16 pass,
+    # which would move weights across histogram bins; the CPU is exact
+    sim = jnp.dot(e1, e2.T, preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)
     sim = jnp.clip(sim, 0.0, 1.0)
     w = jnp.maximum(sim, floor)
     if exponent != 1.0:

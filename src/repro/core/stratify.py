@@ -6,9 +6,10 @@ Two paths:
   contiguous index ranges of the descending order.  Used when the cross
   product fits in memory (paper's own prototype does the same with SortDesc).
 * **streaming/single-sweep** — TPU-native redesign (docs/kernels.md): **one**
-  blocked pass of ``E1 @ E2^T`` (Pallas kernel ``sim_sweep``; blocked
-  numpy fallback here) emits the global weight histogram, per-(row-block,
-  bin) count tiles, and the per-row top-k.  The top-m threshold is the
+  blocked pass of ``E1 @ E2^T`` (Pallas kernel ``sim_sweep``, or the blocked
+  numpy sweep here when the caller passes ``use_kernel=False``) emits the
+  global weight histogram, per-(row-block, bin) count tiles, and the per-row
+  top-k.  The top-m threshold is the
   histogram CDF quantile; collection reads the top-k candidates and rescans
   only the row blocks whose count tiles prove over-threshold mass — so the
   paper's O(N^2 log N^2) sort becomes ~one O(N^2) streaming pass, and the
@@ -123,7 +124,8 @@ def stratify_dense(
 
 
 # ----------------------------------------------------------------------------
-# Single-sweep streaming path (sim_sweep Pallas kernel with numpy fallback).
+# Single-sweep streaming path (sim_sweep Pallas kernel, or the blocked numpy
+# sweep when the caller asks for it with use_kernel=False).
 # ----------------------------------------------------------------------------
 
 # Per-row candidate budget of the sweep's top-k output.  The top-k collection
@@ -148,7 +150,7 @@ class SweepInfo:
 
     ``row_sums``/``total_weight`` are only attached when the sweep ran at
     effective fp32 (kernel compensated accumulation, or the f64 numpy
-    fallback) — low-precision sweeps leave them ``None`` so consumers
+    sweep) — low-precision sweeps leave them ``None`` so consumers
     recompute exactly rather than inherit bf16/int8 error into the
     Horvitz–Thompson weights."""
 
@@ -196,63 +198,29 @@ class SweepInfo:
         return starts, self.block_rows
 
 
-def _kernel_op(module: str, attr: str, *args, **kwargs):
-    """Call a Pallas op with the shared degradation policy: missing Pallas
-    (ImportError) degrades silently to the caller's fallback; any other
-    failure is a real bug and is surfaced as a warning so it cannot hide
-    behind the fallback.  Returns the op's result, or None to fall back."""
-    import importlib
-
-    try:
-        mod = importlib.import_module(module)
-    except ImportError:
-        return None
-    try:
-        return getattr(mod, attr)(*args, **kwargs)
-    except Exception as e:
-        import warnings
-
-        warnings.warn(f"{module}.{attr} failed ({e!r}); using fallback")
-        return None
-
+# The kernel ops are imported where they are called: the index layer and the
+# jax-free launcher modes import this module without touching jax.  A kernel
+# failure propagates to the caller; nothing here substitutes the numpy sweep.
 
 def _kernel_sweep(e1, e2, n_bins, exponent, floor, scale=None,
                   precision="fp32", k_top=TOPK_CANDIDATES, right=None,
                   rs_exponent=None, block=None):
-    """Fused-kernel sweep, or None -> blocked numpy fallback."""
+    """Fused-kernel sweep (``repro.kernels.sim_sweep``)."""
+    from repro.kernels.sim_sweep.ops import sim_sweep
+
     kwargs = dict(k=k_top, scale=scale, precision=precision, right=right,
                   rs_exponent=rs_exponent)
     if block is not None:
         kwargs["block"] = block
-    return _kernel_op(
-        "repro.kernels.sim_sweep.ops", "sim_sweep", e1, e2, n_bins, exponent,
-        floor, **kwargs,
-    )
+    return sim_sweep(e1, e2, n_bins, exponent, floor, **kwargs)
 
 
-def _prepare_sweep_right(e2, precision, n1_hint=None):
-    """Padded/quantised right table for repeated chain sweeps, or None when
-    the kernel layer is unavailable."""
-    return _kernel_op(
-        "repro.kernels.sim_sweep.ops", "prepare_right", e2,
-        precision=precision, n1_hint=n1_hint,
-    )
-
-
-def _warn_lowp_unavailable(precision):
+def _warn_lowp_numpy(precision):
     import warnings
 
     warnings.warn(
-        f"{precision} sweep requested but the Pallas kernel path is "
-        "unavailable; the numpy fallback computes fp32"
-    )
-
-
-def _kernel_hist(e1, e2, n_bins, exponent, floor, scale=None):
-    """Two-pass baseline: fused-kernel histogram, or None -> jnp fallback."""
-    return _kernel_op(
-        "repro.kernels.sim_hist.ops", "sim_hist", e1, e2, n_bins, exponent,
-        floor, scale=scale,
+        f"{precision} sweep requested with use_kernel=False; the numpy "
+        "sweep computes fp32"
     )
 
 
@@ -306,13 +274,13 @@ def sweep_pass(
     power-of-two bucket.
 
     ``k_top`` sizes the top-k output; callers that know collection will go
-    dense (m_cap >= 16 * n1) pass 1 to skip the extract-max cost.  The
-    numpy fallback makes the same single pass in ``block``-row chunks
-    (np.histogram per chunk gives the count tiles for free); it has no
-    top-k output, so collection rescans — but only the blocks the tiles
-    flag.  Low-precision sweeps are tolerance-checked: the first row block
-    is re-binned at fp32 and the whole sweep falls back to fp32 when the
-    CDF deviation exceeds ``tolerance``.
+    dense (m_cap >= 16 * n1) pass 1 to skip the extract-max cost.
+    ``use_kernel=False`` makes the same single pass in numpy, in
+    ``block``-row chunks (np.histogram per chunk gives the count tiles for
+    free); it has no top-k output, so collection rescans — but only the
+    blocks the tiles flag.  Low-precision sweeps are tolerance-checked: the
+    first row block is re-binned at fp32 and the whole sweep falls back to
+    fp32 when the CDF deviation exceeds ``tolerance``.
 
     ``artifact`` (a :class:`repro.core.index.IndexArtifact`) skips the pass
     entirely and hydrates the stored sweep instead — bit-identical at fp32
@@ -330,41 +298,40 @@ def sweep_pass(
         out = _kernel_sweep(e1, e2, n_bins, exponent, floor,
                             precision=precision, k_top=k_top,
                             block=kernel_block)
-        if out is not None:
-            info = SweepInfo(
-                counts=out.counts, edges=out.edges,
-                block_counts=out.block_counts, block_rows=out.block_rows,
-                topk=(out.vals, out.idx, out.valid) if k_top >= 2 else None,
-                kernel=True, precision=precision,
-            )
-            if precision == "fp32":
-                # compensated fused walk sums (~1 f32 ulp of the f64
-                # reference); lowp sums would leak quantisation error into
-                # the HT weights, so those paths recompute instead
-                info.row_sums = [out.row_sums]
-                info.total_weight = float(out.row_sums.sum())
-            if precision != "fp32":
-                rows = min(info.block_rows, e1.shape[0])
-                ref = _binned_counts(pair_weights(e1[:rows], e2, exponent, floor), n_bins)
-                dev = _lowp_cdf_dev(ref, info.block_counts[0])
-                info.stats["lowp_cdf_dev"] = dev
-                if tolerance is not None and dev > tolerance:
-                    import warnings
+        info = SweepInfo(
+            counts=out.counts, edges=out.edges,
+            block_counts=out.block_counts, block_rows=out.block_rows,
+            topk=(out.vals, out.idx, out.valid) if k_top >= 2 else None,
+            kernel=True, precision=precision,
+        )
+        if precision == "fp32":
+            # compensated fused walk sums (~1 f32 ulp of the f64
+            # reference); lowp sums would leak quantisation error into
+            # the HT weights, so those paths recompute instead
+            info.row_sums = [out.row_sums]
+            info.total_weight = float(out.row_sums.sum())
+        else:
+            rows = min(info.block_rows, e1.shape[0])
+            ref = _binned_counts(pair_weights(e1[:rows], e2, exponent, floor), n_bins)
+            dev = _lowp_cdf_dev(ref, info.block_counts[0])
+            info.stats["lowp_cdf_dev"] = dev
+            if tolerance is not None and dev > tolerance:
+                import warnings
 
-                    warnings.warn(
-                        f"{precision} sweep CDF deviation {dev:.4f} exceeds "
-                        f"tolerance {tolerance:.4f}; falling back to fp32"
-                    )
-                    info = sweep_pass(
-                        e1, e2, n_bins, exponent, floor, block, use_kernel,
-                        precision="fp32", k_top=k_top,
-                        kernel_block=kernel_block,
-                    )
-                    info.stats["lowp_fallback"] = dev
-            return info
+                warnings.warn(
+                    f"{precision} sweep CDF deviation {dev:.4f} exceeds "
+                    f"tolerance {tolerance:.4f}; falling back to fp32"
+                )
+                info = sweep_pass(
+                    e1, e2, n_bins, exponent, floor, block, use_kernel,
+                    precision="fp32", k_top=k_top,
+                    kernel_block=kernel_block,
+                )
+                info.stats["lowp_fallback"] = dev
+        return info
 
     if precision != "fp32":
-        _warn_lowp_unavailable(precision)
+        _warn_lowp_numpy(precision)
     edges = np.linspace(0.0, 1.0, n_bins + 1)
     n1 = e1.shape[0]
     tiles = []
@@ -436,8 +403,6 @@ def sweep_pass_chain(
         n_prefix *= e.shape[0]
     edges = np.linspace(0.0, 1.0, n_bins + 1)
     tiles = []
-    kernel_ok = use_kernel
-    kernel_tiles = 0
     lowp_dev = None
     # walk statistics, fused into the same prefix sweeps: the last-edge row
     # sums r[i] = sum_c w_last(i, c) (every i in the last prefix table is
@@ -446,19 +411,18 @@ def sweep_pass_chain(
     r_last = np.zeros(e_prev.shape[0], np.float64)
     total = 0.0
     right = None  # right table padded/quantised once, swept per prefix block
-    if kernel_ok:
-        right = _prepare_sweep_right(e_last, precision,
-                                     n1_hint=min(block, n_prefix))
-        kernel_ok = right is not None
-    if not kernel_ok and precision != "fp32":
-        _warn_lowp_unavailable(precision)
+    if use_kernel:
+        from repro.kernels.sim_sweep.ops import prepare_right
+
+        right = prepare_right(e_last, precision=precision,
+                              n1_hint=min(block, n_prefix))
+    elif precision != "fp32":
+        _warn_lowp_numpy(precision)
     for s in range(0, n_prefix, block):
         wp, i_last = _prefix_chain_weights(
             embeddings, s, min(s + block, n_prefix), exponent, floor
         )
-        tile = None
-        rs_blk = None
-        if kernel_ok:
+        if use_kernel:
             # kernel bins max(clip(sim), floor)**(e*root) * scale —
             # exactly (wp * w_last)**root when scale = wp**root; the walk
             # sums ride along at the raw full exponent (rs_exponent)
@@ -467,30 +431,26 @@ def sweep_pass_chain(
                 scale=wp**root, precision=precision, k_top=1, right=right,
                 rs_exponent=exponent,
             )
-            if out is None:
-                kernel_ok = False
-            else:
-                tile = out.counts
-                rs_blk = out.row_sums
-                kernel_tiles += 1
-                if precision != "fp32" and s == 0:
-                    w = pair_weights(e_prev[i_last], e_last, exponent * root, floor)
-                    ref = _binned_counts(wp[:, None] ** root * w, n_bins)
-                    dev = lowp_dev = _lowp_cdf_dev(ref, tile)
-                    if tolerance is not None and dev > tolerance:
-                        import warnings
+            tile = out.counts
+            rs_blk = out.row_sums
+            if precision != "fp32" and s == 0:
+                w = pair_weights(e_prev[i_last], e_last, exponent * root, floor)
+                ref = _binned_counts(wp[:, None] ** root * w, n_bins)
+                dev = lowp_dev = _lowp_cdf_dev(ref, tile)
+                if tolerance is not None and dev > tolerance:
+                    import warnings
 
-                        warnings.warn(
-                            f"{precision} chain sweep CDF deviation {dev:.4f} "
-                            f"exceeds tolerance {tolerance:.4f}; using fp32"
-                        )
-                        info = sweep_pass_chain(
-                            embeddings, n_bins, exponent, floor, block,
-                            use_kernel, precision="fp32",
-                        )
-                        info.stats["lowp_fallback"] = dev
-                        return info
-        if tile is None:
+                    warnings.warn(
+                        f"{precision} chain sweep CDF deviation {dev:.4f} "
+                        f"exceeds tolerance {tolerance:.4f}; using fp32"
+                    )
+                    info = sweep_pass_chain(
+                        embeddings, n_bins, exponent, floor, block,
+                        use_kernel, precision="fp32",
+                    )
+                    info.stats["lowp_fallback"] = dev
+                    return info
+        else:
             w = pair_weights(e_prev[i_last], e_last, exponent, floor)
             rs_blk = w.sum(axis=1)
             v = (wp[:, None] * w) ** root
@@ -500,20 +460,15 @@ def sweep_pass_chain(
         r_last[i_last] = rs_blk
         tiles.append(tile)
     bc = np.stack(tiles) if tiles else np.zeros((1, n_bins), np.int64)
-    # the precision label drives blocks_over's safety margin: any tile binned
-    # at low precision makes the whole sweep low-precision for that purpose,
-    # even if the kernel died mid-loop and later tiles are fp32
-    used_lowp = kernel_tiles > 0 and precision != "fp32"
+    # the precision label drives blocks_over's safety margin
+    used_lowp = use_kernel and precision != "fp32"
     info = SweepInfo(
         counts=bc.sum(axis=0), edges=edges, block_counts=bc, block_rows=block,
-        topk=None, kernel=kernel_ok,
+        topk=None, kernel=use_kernel,
         precision=precision if used_lowp else "fp32",
     )
     if used_lowp and lowp_dev is not None:
         info.stats["lowp_cdf_dev"] = lowp_dev
-    if kernel_tiles and not kernel_ok:
-        info.stats["kernel_tiles"] = kernel_tiles
-        info.stats["numpy_tiles"] = len(tiles) - kernel_tiles
     if not used_lowp:
         # earlier edges are small inter-table products (already paid inside
         # the prefix tuple weights); only the last cross-product edge was
@@ -541,9 +496,9 @@ def weight_histogram(
     from .similarity import pair_weights  # local import to avoid cycle
 
     if use_kernel:
-        out = _kernel_hist(e1, e2, n_bins, exponent, floor)
-        if out is not None:
-            return out
+        from repro.kernels.sim_hist.ops import sim_hist
+
+        return sim_hist(e1, e2, n_bins, exponent, floor)
 
     edges = np.linspace(0.0, 1.0, n_bins + 1)
     counts = np.zeros(n_bins, np.int64)
@@ -581,20 +536,18 @@ def chain_weight_histogram(
         n_prefix *= e.shape[0]
     edges = np.linspace(0.0, 1.0, n_bins + 1)
     counts = np.zeros(n_bins, np.int64)
+    if use_kernel:
+        from repro.kernels.sim_hist.ops import sim_hist
     for s in range(0, n_prefix, block):
         wp, i_last = _prefix_chain_weights(
             embeddings, s, min(s + block, n_prefix), exponent, floor
         )
-        done = False
         if use_kernel:
-            out = _kernel_hist(
+            counts += sim_hist(
                 e_prev[i_last], e_last, n_bins, exponent * root, floor,
                 scale=wp**root,
-            )
-            if out is not None:
-                counts += out[0]
-                done = True
-        if not done:
+            )[0]
+        else:
             w = pair_weights(e_prev[i_last], e_last, exponent, floor)
             v = (wp[:, None] * w) ** root
             c, _ = np.histogram(v, bins=edges)
@@ -617,9 +570,10 @@ def threshold_for_top_m(counts: np.ndarray, edges: np.ndarray, m: int) -> float:
     return float(edges[ok[-1]])
 
 
-def _try_sim_topk(e1, e2, k):
-    """sim_topk kernel call, or None -> dense scan."""
-    return _kernel_op("repro.kernels.sim_topk.ops", "sim_topk", e1, e2, k=k)
+def _sim_topk(e1, e2, k):
+    from repro.kernels.sim_topk.ops import sim_topk
+
+    return sim_topk(e1, e2, k=k)
 
 
 def _collect_from_topk(e1, e2, vals, idx, valid, threshold, exponent, floor,
@@ -650,9 +604,8 @@ def _collect_from_topk(e1, e2, vals, idx, valid, threshold, exponent, floor,
         # a deep threshold saturates most rows; the retry would likely
         # saturate too, so go straight to the exact rescan
         retry_pays = len(saturated) <= n1 // 4
-        out = _try_sim_topk(e1[saturated], e2, k2) if k2 > kk and retry_pays else None
-        if out is not None:
-            v2, i2, valid2 = out
+        if k2 > kk and retry_pays:
+            v2, i2, valid2 = _sim_topk(e1[saturated], e2, k2)
             w2 = weight_of_score(np.asarray(v2, np.float64), exponent, floor)
             keep2 = (w2 >= threshold) & valid2
             if v2.shape[1] < n2:
@@ -678,11 +631,8 @@ def _collect_from_topk(e1, e2, vals, idx, valid, threshold, exponent, floor,
 
 def _collect_top_pairs_topk(e1, e2, threshold, exponent, floor, stats=None):
     """Two-pass baseline: run sim_topk now, then collect (see
-    :func:`_collect_from_topk`).  None when the kernel is unavailable."""
-    out = _try_sim_topk(e1, e2, k=min(TOPK_CANDIDATES, e2.shape[0]))
-    if out is None:
-        return None
-    vals, idx, valid = out
+    :func:`_collect_from_topk`)."""
+    vals, idx, valid = _sim_topk(e1, e2, k=min(TOPK_CANDIDATES, e2.shape[0]))
     return _collect_from_topk(
         e1, e2, vals, idx, valid, threshold, exponent, floor, stats=stats
     )
@@ -705,7 +655,7 @@ def collect_top(
 
     With a :class:`SweepInfo` the candidates come straight from the sweep's
     top-k output (no second kernel pass) and any rescan — truncated rows,
-    or the whole collection on the fallback path — touches only the row
+    or the whole collection on the numpy path — touches only the row
     blocks whose count tiles show over-threshold mass."""
     from .similarity import pair_weights
 

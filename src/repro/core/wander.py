@@ -24,6 +24,9 @@ from .similarity import pair_weights
 from .types import ConfidenceInterval
 
 
+_CHUNK_ELEMS = 1 << 25  # 256 MiB of f64 walk-step weights per chunk
+
+
 @dataclasses.dataclass
 class WalkSample:
     idx: np.ndarray    # (n, k) tuple indices
@@ -49,21 +52,26 @@ def walk_sample(
     floor: float = 1e-3,
     chunk: int = 4096,
 ) -> WalkSample:
-    """n independent WWJ random walks over a k-table chain."""
+    """n independent WWJ random walks over a k-table chain.  Each step
+    materialises (rows, N_next) f64 weights for up to ``chunk`` walks at a
+    time, fewer where N_next is wide, so a chunk holds at most
+    ``_CHUNK_ELEMS`` weights and concurrent queries stay within host
+    memory.  The draws do not depend on the chunking."""
     k = len(embeddings)
     n1 = embeddings[0].shape[0]
     idx = np.empty((n, k), np.int64)
     prob = np.full((n,), 1.0 / n1, np.float64)
     idx[:, 0] = rng.integers(0, n1, size=n)
     for step in range(k - 1):
-        for s in range(0, n, chunk):
-            cur = idx[s : s + chunk, step]
+        rows = max(1, min(chunk, _CHUNK_ELEMS // embeddings[step + 1].shape[0]))
+        for s in range(0, n, rows):
+            cur = idx[s : s + rows, step]
             w = pair_weights(
                 embeddings[step][cur], embeddings[step + 1], exponent, floor
             )
             nxt, p = _categorical_rows(w, rng)
-            idx[s : s + chunk, step + 1] = nxt
-            prob[s : s + chunk] *= p
+            idx[s : s + rows, step + 1] = nxt
+            prob[s : s + rows] *= p
     return WalkSample(idx=idx, prob=prob)
 
 

@@ -64,6 +64,29 @@ def make_entity_corpus(
     return records, np.array(ids)
 
 
+def entity_records(entity_ids: np.ndarray, n_entities: int, seed: int = 0,
+                   table: int = 0, noise: float = 0.1) -> list:
+    """One record string per row of a table whose rows denote the latent
+    entities ``entity_ids`` (< ``n_entities``): every entity has a base
+    string shared by all tables (drawn from ``seed``), and each row is a
+    noisy copy of it (character substitutions at rate ``noise``, drawn from
+    ``(seed, table)``) — the string side of
+    :func:`repro.data.make_clustered_tables`."""
+    words = np.asarray(_WORDS)
+    picks = np.random.default_rng(seed).integers(0, len(words),
+                                                 (n_entities, 3))
+    rng = np.random.default_rng([seed, table])
+    out = []
+    for e in np.asarray(entity_ids).tolist():
+        base = np.frombuffer(
+            (f"{e} " + " ".join(words[picks[e]])).encode(), np.uint8
+        ).copy()
+        hit = rng.random(len(base)) < noise
+        base[hit] = rng.integers(ord("a"), ord("z") + 1, int(hit.sum()))
+        out.append(base.tobytes().decode())
+    return out
+
+
 def pair_example(
     tok: ByteTokenizer, r1: str, r2: str, label: Optional[int], max_len: int
 ) -> tuple[np.ndarray, np.ndarray]:

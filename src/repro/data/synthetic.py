@@ -33,6 +33,8 @@ class PairDataset:
     columns1: dict = dataclasses.field(default_factory=dict)
     columns2: dict = dataclasses.field(default_factory=dict)
     weights_override: Optional[np.ndarray] = None  # flat scores (Syn datasets)
+    ids1: Optional[np.ndarray] = None    # latent entity id per row (clustered)
+    ids2: Optional[np.ndarray] = None
 
     @property
     def selectivity(self) -> float:
@@ -95,6 +97,8 @@ def make_clustered_tables(
         truth=truth,
         columns1=cols1,
         columns2=cols2,
+        ids1=e1_ids,
+        ids2=e2_ids,
     )
 
 

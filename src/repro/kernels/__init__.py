@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the stratification hot path (``sim_sweep`` and the
+two-pass ``sim_hist`` + ``sim_topk`` pair) and the model kernels."""
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run under the interpreter: compiled on a TPU,
+    interpreted on every other backend.  The ops decide it here and nowhere
+    else, so no caller on a TPU can reach interpret mode by omission."""
+    import jax
+
+    return jax.default_backend() != "tpu"

@@ -5,24 +5,27 @@ schedule.  The historical defaults (256, 256) are sensible on one TPU
 generation but leave throughput on the table on others — and hardcoding a
 single shape makes per-accelerator perf gates (``bench_diff
 --require-compiled``) brittle.  This module measures a small candidate set
-on **first compiled use** per (op, backend, device kind, dtype, shape
-bucket), caches the winner in memory and on disk, and the ops route their
-block choice through :func:`schedule`.
+on **first use on a TPU** per (op, device kind, dtype, shape bucket),
+caches the winner in memory and on disk, and the ops route their block
+choice through :func:`schedule`.
 
 Behaviour contract:
 
-* On non-compiled backends (CPU / interpret mode) :func:`schedule` returns
-  ``None`` immediately — zero measurement, zero behaviour change, so CI and
-  the numerics tests never depend on tuning.
+* Off the TPU (the Pallas interpreter) :func:`schedule` returns ``None``
+  immediately — zero measurement, zero behaviour change, so CI and the
+  numerics tests never depend on tuning.
+* On a TPU whose device kind is not in :data:`DEVICE_KINDS` it raises: the
+  candidates are only known to compile (fit VMEM) for the kinds listed
+  there, which ``tests/test_tpu_compile.py`` checks for every candidate.
 * Shapes are bucketed to powers of two; one measurement serves every shape
   in the bucket.
 * The disk cache is a single JSON file (``autotune.json``), written
   atomically next to the index store when one is configured
   (:meth:`repro.core.index.IndexStore`), so tuned schedules survive process
   restarts and ship with the index artifacts they accelerate.
-* Measurement failures (OOM on an exotic candidate, unsupported shape) are
-  swallowed per-candidate; if every candidate fails the op falls back to
-  its built-in defaults.
+* A candidate that fails to compile or run raises; nothing falls back to
+  a default schedule.  A candidate that does not fit is removed from
+  :data:`CANDIDATES`, not caught at run time.
 
 The module deliberately avoids importing jax at module scope so that
 configuring the cache path from the (jax-free) index layer stays cheap.
@@ -35,9 +38,10 @@ import threading
 from typing import Optional
 
 # (row-block, col-block) candidates, all power-of-two so the ops' padding
-# math and the kernel's pairwise reduction stay exact
+# math and the kernel's pairwise reduction stay exact, and every one
+# compile-checked for each kind in DEVICE_KINDS (tests/test_tpu_compile.py)
 CANDIDATES = ((256, 256), (128, 256), (256, 128), (512, 256), (256, 512))
-COMPILED_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
+DEVICE_KINDS = ("TPU v5 lite",)
 
 _lock = threading.Lock()
 _memory: dict[str, tuple[int, int]] = {}
@@ -72,9 +76,9 @@ def _bucket(x: int) -> int:
     return max(8, 1 << (max(int(x), 1) - 1).bit_length())
 
 
-def _key(op: str, backend: str, device_kind: str, precision: str,
+def _key(op: str, device_kind: str, precision: str,
          m: int, n: int, d: int) -> str:
-    return (f"{op}/{backend}/{device_kind}/{precision}/"
+    return (f"{op}/{device_kind.replace(' ', '_')}/{precision}/"
             f"{_bucket(m)}x{_bucket(n)}x{_bucket(d)}")
 
 
@@ -126,6 +130,16 @@ def _time_candidate(op: str, m: int, n: int, d: int, precision: str,
 
         def run():
             return sim_hist_pallas(e1, e2, bm=bm, bn=bn, interpret=False)
+    elif precision == "int8":
+        from .sim_sweep.kernel import sim_sweep_q_pallas
+
+        q1, q2 = (jnp.clip(jnp.round(e * 32), -127, 127).astype(jnp.int8)
+                  for e in (e1, e2))
+        r1, r2 = jnp.ones((m, 1), jnp.float32), jnp.ones((1, n), jnp.float32)
+
+        def run():
+            return sim_sweep_q_pallas(q1, q2, r1, r2, bm=bm, bn=bn,
+                                      interpret=False)
     else:
         from .sim_sweep.kernel import sim_sweep_pallas
 
@@ -145,39 +159,32 @@ def _time_candidate(op: str, m: int, n: int, d: int, precision: str,
 
 
 def _measure(op: str, m: int, n: int, d: int, precision: str,
-             candidates) -> Optional[tuple[int, int]]:
-    """Return the fastest feasible (bm, bn) candidate, or None."""
-    best = None
-    best_t = float("inf")
-    for bm, bn in candidates:
-        try:
-            t = _time_candidate(op, m, n, d, precision, bm, bn)
-        except Exception:  # OOM / unsupported shape: skip this candidate
-            continue
-        if t < best_t:
-            best, best_t = (bm, bn), t
-    return best
+             candidates) -> tuple[int, int]:
+    """Return the fastest (bm, bn) candidate; a failing candidate raises."""
+    times = {c: _time_candidate(op, m, n, d, precision, *c)
+             for c in candidates}
+    return min(times, key=times.get)
 
 
 def schedule(op: str, m: int, n: int, d: int, precision: str = "fp32",
-             backend: Optional[str] = None) -> Optional[tuple[int, int]]:
+             device_kind: Optional[str] = None) -> Optional[tuple[int, int]]:
     """Tuned (row-block, col-block) for ``op`` at this shape bucket, or
-    ``None`` when not on a compiled backend (callers keep their defaults).
-    First compiled use per bucket measures :data:`CANDIDATES` and persists
-    the winner."""
-    if backend is None:
+    ``None`` off the TPU (callers keep their defaults).  ``device_kind``
+    defaults to the first device's.  First use per bucket measures
+    :data:`CANDIDATES` and persists the winner."""
+    if device_kind is None:
         import jax
 
-        backend = jax.default_backend()
-    if backend not in COMPILED_BACKENDS:
-        return None
-    try:
-        import jax
-
-        device_kind = jax.devices(backend)[0].device_kind.replace(" ", "_")
-    except Exception:
-        device_kind = backend
-    key = _key(op, backend, device_kind, precision, m, n, d)
+        if jax.default_backend() != "tpu":
+            return None
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in DEVICE_KINDS:
+        raise RuntimeError(
+            f"no block schedules are verified for device kind "
+            f"{device_kind!r} (known: {DEVICE_KINDS}); compile-check "
+            "CANDIDATES for it and add it to DEVICE_KINDS"
+        )
+    key = _key(op, device_kind, precision, m, n, d)
     with _lock:
         _load_locked()
         if key in _memory:
@@ -187,8 +194,6 @@ def schedule(op: str, m: int, n: int, d: int, precision: str = "fp32",
     if not cands:
         cands = [(min(CANDIDATES[0][0], bm_cap), min(CANDIDATES[0][1], bn_cap))]
     won = _measure(op, _bucket(m), _bucket(n), _bucket(d), precision, cands)
-    if won is None:
-        return None
     with _lock:
         _memory[key] = won
         _save_locked()

@@ -75,7 +75,8 @@ def flash_attention_pallas(
     window: int = 0,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape

@@ -5,10 +5,27 @@ padded-pair contributions from their histograms afterwards.  The two
 corrections MUST stay bit-identical — the single-sweep stratifier's
 fp32 bit-identity guarantee (sweep vs two-pass strata) rests on it — so both
 ops import these helpers instead of carrying copies.
+
+Block shapes follow the TPU's (8, 128) tiling: a row block is a power of two
+of at least 8 rows, and a column block — the lane dimension of the per-column
+operands and of the score tile — at least 128 columns.  Interpret mode uses
+the same shapes, so CPU runs pad exactly as the chip does.
 """
 from __future__ import annotations
 
 import numpy as np
+
+from .binning import LANE
+
+
+def row_block(block: int, n: int) -> int:
+    """Rows per block: the power of two covering ``n``, capped at ``block``."""
+    return min(block, max(8, 1 << (max(n, 1) - 1).bit_length()))
+
+
+def col_block(block: int, n: int) -> int:
+    """Columns per block: :func:`row_block`, but never under one lane width."""
+    return max(LANE, row_block(block, n))
 
 
 def pad_rows(e: np.ndarray, mult: int) -> tuple[np.ndarray, int]:

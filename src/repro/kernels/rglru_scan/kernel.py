@@ -55,7 +55,8 @@ def rglru_scan_pallas(
     g: jax.Array,   # (B, T, R) gated input
     ct: int = 128,
     br: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, t, r = a.shape
     assert t % ct == 0 and r % br == 0

@@ -65,7 +65,8 @@ def rwkv6_scan_pallas(
     w: jax.Array,   # decay in (0,1)
     u: jax.Array,   # (H, hd) bonus
     ct: int = 64,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, h, t, hd = r.shape
     assert t % ct == 0

@@ -16,9 +16,9 @@ Grid: (M/bm, N/bn), sequential on TPU so the histogram accumulates safely in
 the output block (same output block mapped to every program).
 
 The binning epilogue is shared with ``sim_sweep`` (``repro.kernels.binning``):
-a two-level one-hot + MXU combine replaces the original O(n_bins)-compares-
-per-element chunked scan whenever the bin count decomposes (scan fallback
-otherwise).  Counts are bit-identical either way.
+a two-level one-hot + MXU combine in place of O(n_bins) compares per
+element; the histogram accumulates as an (n_hi, lane) tile and is flattened
+to (n_bins,) outside the kernel.
 """
 from __future__ import annotations
 
@@ -27,12 +27,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..binning import bin_counts, plan_bins
+from ..binning import bin_counts, bin_layout, flat_counts
+from ..sim_topk.kernel import F32_DOT
 
 
-def _kernel(e1_ref, e2_ref, s_ref, out_ref, *, n_bins: int, exponent: float,
-            floor: float, plan):
+def _kernel(e1_ref, e2_ref, s_ref, out_ref, idx_buf, *, n_bins: int,
+            exponent: float, floor: float):
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -42,7 +44,8 @@ def _kernel(e1_ref, e2_ref, s_ref, out_ref, *, n_bins: int, exponent: float,
     e1 = e1_ref[...].astype(jnp.float32)
     e2 = e2_ref[...].astype(jnp.float32)
     scores = jax.lax.dot_general(
-        e1, e2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        e1, e2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT,
     )
     w = jnp.clip(scores, 0.0, 1.0)
     w = jnp.maximum(w, floor)
@@ -50,38 +53,37 @@ def _kernel(e1_ref, e2_ref, s_ref, out_ref, *, n_bins: int, exponent: float,
         w = w**exponent
     w = w * s_ref[...].astype(jnp.float32)  # (bm, 1) prefix weights broadcast
     idx = jnp.clip((w * n_bins).astype(jnp.int32), 0, n_bins - 1)
-    out_ref[...] = out_ref[...] + bin_counts(idx, n_bins, plan)
+    out_ref[...] = out_ref[...] + bin_counts(idx, n_bins, idx_buf)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_bins", "exponent", "floor", "bm", "bn", "bin_chunk",
-                     "interpret"),
+    static_argnames=("n_bins", "exponent", "floor", "bm", "bn", "interpret"),
 )
 def sim_hist_pallas(
     e1: jax.Array,
     e2: jax.Array,
     scale: jax.Array | None = None,
+    *,
+    interpret: bool,
     n_bins: int = 4096,
     exponent: float = 1.0,
     floor: float = 1e-3,
     bm: int = 256,
     bn: int = 256,
-    bin_chunk: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     m, d = e1.shape
     n, _ = e2.shape
     assert m % bm == 0 and n % bn == 0, "pad inputs to block multiples"
-    plan = plan_bins(n_bins, bm * bn, bin_chunk)
+    n_hi, lane = bin_layout(n_bins)
     if scale is None:
         scale = jnp.ones((m, 1), jnp.float32)
     else:
         scale = scale.reshape(m, 1).astype(jnp.float32)
     grid = (m // bm, n // bn)
-    return pl.pallas_call(
+    tile = pl.pallas_call(
         functools.partial(
-            _kernel, n_bins=n_bins, exponent=exponent, floor=floor, plan=plan,
+            _kernel, n_bins=n_bins, exponent=exponent, floor=floor,
         ),
         grid=grid,
         in_specs=[
@@ -89,7 +91,10 @@ def sim_hist_pallas(
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((n_bins,), lambda i, j: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n_bins,), jnp.int32),
+        out_specs=pl.BlockSpec((n_hi, lane), lambda i, j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_hi, lane), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="sim_hist",
     )(e1, e2, scale)
+    return flat_counts(tile, n_bins)

@@ -27,21 +27,27 @@ power ``rs_exponent`` (chain sweeps bin the geometric-mean weight at
 ``exponent * root`` but need the raw full-exponent edge weight in the sums).
 
 Walk sums are accumulated with **compensated f32 arithmetic**: each (bm, bn)
-block is reduced by an error-free pairwise tree that carries (hi, lo) pairs
-through branch-free Knuth two-sum steps, and the cross-block running total
-lives in two VMEM scratch vectors (sum, compensation).  The result matches a
+block is reduced by an error-free tree that carries (hi, lo) pairs through
+branch-free Knuth two-sum steps — lane-aligned halving down to one vreg
+width, then a lane-rotation butterfly — and the cross-block running total
+lives in two VMEM scratch tiles (sum, compensation).  The result matches a
 float64 reference to ~1 ulp of f32 (|rel err| ~1e-7) regardless of the
 column count or magnitude spread — naive sequential f32 accumulation loses
 several digits at these reduction lengths (see ``tests/test_chain_stats``).
 
-Precision paths (static ``compute_dtype``): fp32 casts inputs to f32 before
-the MXU (bit-identical to the sim_hist/sim_topk pair); bf16 feeds the MXU
+Precision paths (static ``compute_dtype``): fp32 feeds the MXU f32 inputs
+at ``HIGHEST`` precision (bit-identical to the sim_hist/sim_topk pair;
+Mosaic runs a default-precision f32 dot as one bf16 pass, ~5e-4 error on
+unit vectors on a v5e); bf16 feeds the MXU
 bf16 inputs with f32 accumulation; the int8 variant (``sim_sweep_q_pallas``)
 takes per-row-quantised int8 embeddings + scales, accumulates in int32 on
 the MXU and rescales to f32 scores.
 
 Grid: (M/bm, N/bn); the N dimension iterates sequentially (TPU grid order),
-the count tile, top-k and walk-sum scratch are initialised at j == 0.
+the count tile, top-k and walk-sum scratch are initialised at j == 0.  Every
+block shape obeys the TPU's (8, 128) rule: count tiles are (n_hi, lane)
+(``repro.kernels.binning``) and the walk sums are carried lane-wide; the
+wrapper reshapes both to the documented outputs outside the kernel.
 """
 from __future__ import annotations
 
@@ -52,9 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..binning import bin_counts, plan_bins
-
-NEG = -1e30
+from ..binning import LANE, bin_counts, bin_layout, flat_counts
+from ..sim_topk.kernel import F32_DOT, NEG, merge_topk
 
 
 def two_sum(a, b):
@@ -66,26 +71,31 @@ def two_sum(a, b):
 
 
 def comp_block_sum(x):
-    """Error-free pairwise reduction along axis 1: returns (hi, lo) column
-    vectors with ``sum(x, axis=1) == hi + lo`` to ~1 ulp.  The tree halves
-    the width each level, carrying per-lane compensation terms, so the whole
-    reduction stays vectorised on the VPU (log2(width) levels)."""
+    """Error-free reduction along axis 1, inside a kernel: returns (hi, lo)
+    tiles of width ``min(width, LANE)`` whose lane 0 satisfies
+    ``sum(x, axis=1) == hi + lo`` to ~1 ulp.  Lane-aligned halving takes the
+    width down to one vreg, then a rotation butterfly (``pltpu.roll``) folds
+    the lanes; every step is a two-sum carrying per-lane compensation, so
+    the reduction stays vectorised on the VPU."""
     hi = x
     lo = jnp.zeros_like(x)
-    while hi.shape[1] > 1:
-        if hi.shape[1] % 2:  # pad one zero column so the halves line up
-            hi = jnp.concatenate([hi, jnp.zeros_like(hi[:, :1])], axis=1)
-            lo = jnp.concatenate([lo, jnp.zeros_like(lo[:, :1])], axis=1)
+    while hi.shape[1] > LANE:
         half = hi.shape[1] // 2
         s, e = two_sum(hi[:, :half], hi[:, half:])
         lo = lo[:, :half] + lo[:, half:] + e
         hi = s
+    shift = hi.shape[1] // 2
+    while shift:
+        s, e = two_sum(hi, pltpu.roll(hi, shift, 1))
+        lo = lo + pltpu.roll(lo, shift, 1) + e
+        hi = s
+        shift //= 2
     return hi, lo
 
 
 def _fused_epilogue(scores, s, v, bc_ref, vals_ref, idx_ref, rs_ref, run_v,
-                    run_i, rs_hi, rs_lo, *, n_bins, exponent, rs_exponent,
-                    floor, k, bn, n_blocks, plan):
+                    run_i, rs_hi, rs_lo, idx_buf, *, n_bins, exponent,
+                    rs_exponent, floor, k, bn, n_blocks):
     """Shared histogram + top-k + walk-sum epilogue over one (bm, bn) block."""
     j = pl.program_id(1)
 
@@ -94,7 +104,7 @@ def _fused_epilogue(scores, s, v, bc_ref, vals_ref, idx_ref, rs_ref, run_v,
     w = base if exponent == 1.0 else base**exponent
     w = w * s.astype(jnp.float32)  # (bm, 1) prefix weights broadcast
     idx = jnp.clip((w * n_bins).astype(jnp.int32), 0, n_bins - 1)
-    bc_ref[...] = bc_ref[...] + bin_counts(idx, n_bins, plan).reshape(1, n_bins)
+    bc_ref[...] = bc_ref[...] + bin_counts(idx, n_bins, idx_buf)
 
     # ---- walk-sum half: compensated accumulation of the raw edge weight
     # times the backward vector.  Padded columns carry v == 0 and vanish, so
@@ -108,25 +118,8 @@ def _fused_epilogue(scores, s, v, bc_ref, vals_ref, idx_ref, rs_ref, run_v,
 
     # ---- top-k half: raw clipped scores, identical math to sim_topk
     sc = jnp.clip(scores, 0.0, 1.0)
-    bm = sc.shape[0]
-    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
-
-    cand_v = jnp.concatenate([run_v[...], sc], axis=1)       # (bm, k+bn)
-    cand_i = jnp.concatenate([run_i[...], col], axis=1)
-    width = k + bn
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bm, width), 1)
-
-    new_v = jnp.full((bm, k), NEG, jnp.float32)
-    new_i = jnp.zeros((bm, k), jnp.int32)
-    for t in range(k):  # k extract-max passes (k is static and small)
-        m = jnp.max(cand_v, axis=1)                           # (bm,)
-        am = jnp.argmax(cand_v, axis=1).astype(jnp.int32)     # (bm,)
-        sel = iota == am[:, None]
-        picked_i = jnp.sum(jnp.where(sel, cand_i, 0), axis=1)
-        new_v = new_v.at[:, t].set(m)
-        new_i = new_i.at[:, t].set(picked_i)
-        cand_v = jnp.where(sel, NEG, cand_v)
-
+    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    new_v, new_i = merge_topk(run_v[...], run_i[...], sc, col, k)
     run_v[...] = new_v
     run_i[...] = new_i
 
@@ -148,25 +141,23 @@ def _init(bc_ref, run_v, run_i, rs_hi, rs_lo):
 
 
 def _kernel(e1_ref, e2_ref, s_ref, v_ref, bc_ref, vals_ref, idx_ref, rs_ref,
-            run_v, run_i, rs_hi, rs_lo, *, n_bins, exponent, rs_exponent,
-            floor, k, bn, n_blocks, plan, compute_dtype):
+            run_v, run_i, rs_hi, rs_lo, idx_buf, *, compute_dtype, **kw):
     _init(bc_ref, run_v, run_i, rs_hi, rs_lo)
     e1 = e1_ref[...].astype(compute_dtype)
     e2 = e2_ref[...].astype(compute_dtype)
     scores = jax.lax.dot_general(
-        e1, e2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        e1, e2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT if compute_dtype == jnp.float32 else None,
     )
     _fused_epilogue(
         scores, s_ref[...], v_ref[...], bc_ref, vals_ref, idx_ref, rs_ref,
-        run_v, run_i, rs_hi, rs_lo, n_bins=n_bins, exponent=exponent,
-        rs_exponent=rs_exponent, floor=floor, k=k, bn=bn, n_blocks=n_blocks,
-        plan=plan,
+        run_v, run_i, rs_hi, rs_lo, idx_buf, **kw,
     )
 
 
 def _kernel_q(q1_ref, q2_ref, s_ref, rs1_ref, rs2_ref, v_ref, bc_ref,
-              vals_ref, idx_ref, rs_ref, run_v, run_i, rs_hi, rs_lo, *,
-              n_bins, exponent, rs_exponent, floor, k, bn, n_blocks, plan):
+              vals_ref, idx_ref, rs_ref, run_v, run_i, rs_hi, rs_lo, idx_buf,
+              **kw):
     _init(bc_ref, run_v, run_i, rs_hi, rs_lo)
     acc = jax.lax.dot_general(
         q1_ref[...], q2_ref[...], (((1,), (1,)), ((), ())),
@@ -175,45 +166,71 @@ def _kernel_q(q1_ref, q2_ref, s_ref, rs1_ref, rs2_ref, v_ref, bc_ref,
     scores = acc.astype(jnp.float32) * rs1_ref[...] * rs2_ref[...]
     _fused_epilogue(
         scores, s_ref[...], v_ref[...], bc_ref, vals_ref, idx_ref, rs_ref,
-        run_v, run_i, rs_hi, rs_lo, n_bins=n_bins, exponent=exponent,
-        rs_exponent=rs_exponent, floor=floor, k=k, bn=bn, n_blocks=n_blocks,
-        plan=plan,
+        run_v, run_i, rs_hi, rs_lo, idx_buf, **kw,
     )
 
 
-def _out_shapes(m, n_bins, k, bm):
-    return (
-        [
-            pl.BlockSpec((1, n_bins), lambda i, j: (i, 0)),
+def _sweep_call(kernel, operands, in_specs, *, m, n, n_bins, exponent,
+                rs_exponent, floor, k, bm, bn, interpret):
+    """pallas_call shared by both precision paths, plus the reshapes from
+    the kernel's tile layouts to the documented outputs."""
+    assert m % bm == 0 and n % bn == 0, "pad inputs to block multiples"
+    assert k <= bn
+    n_hi, lane = bin_layout(n_bins)
+    rs_w = min(bn, LANE)
+    rs_exp = exponent if rs_exponent is None else rs_exponent
+    bc, vals, idx, rs = pl.pallas_call(
+        functools.partial(
+            kernel, n_bins=n_bins, exponent=exponent, rs_exponent=rs_exp,
+            floor=floor, k=k, bn=bn, n_blocks=n // bn,
+        ),
+        grid=(m // bm, n // bn),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((None, n_hi, lane), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, rs_w), lambda i, j: (i, 0)),
         ],
-        [
-            jax.ShapeDtypeStruct((m // bm, n_bins), jnp.int32),
+        out_shape=[
+            jax.ShapeDtypeStruct((m // bm, n_hi, lane), jnp.int32),
             jax.ShapeDtypeStruct((m, k), jnp.float32),
             jax.ShapeDtypeStruct((m, k), jnp.int32),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m, rs_w), jnp.float32),
         ],
-        [
+        scratch_shapes=[
             pltpu.VMEM((bm, k), jnp.float32),
             pltpu.VMEM((bm, k), jnp.int32),
-            pltpu.VMEM((bm, 1), jnp.float32),
-            pltpu.VMEM((bm, 1), jnp.float32),
+            pltpu.VMEM((bm, rs_w), jnp.float32),
+            pltpu.VMEM((bm, rs_w), jnp.float32),
+            pltpu.VMEM((bm, bn), jnp.int32),
         ],
-    )
+        interpret=interpret,
+        name="sim_sweep",
+    )(*operands)
+    return flat_counts(bc, n_bins), vals, idx, rs[:, :1]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_bins", "exponent", "rs_exponent", "floor", "k", "bm",
-                     "bn", "bin_chunk", "interpret", "compute_dtype"),
-)
+def _row_col_operands(m, n, scale, v):
+    scale = (jnp.ones((m, 1), jnp.float32) if scale is None
+             else scale.reshape(m, 1).astype(jnp.float32))
+    v = (jnp.ones((1, n), jnp.float32) if v is None
+         else v.reshape(1, n).astype(jnp.float32))
+    return scale, v
+
+
+_STATIC = ("n_bins", "exponent", "rs_exponent", "floor", "k", "bm", "bn",
+           "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("compute_dtype",))
 def sim_sweep_pallas(
     e1: jax.Array,
     e2: jax.Array,
     scale: jax.Array | None = None,
     v: jax.Array | None = None,
+    *,
+    interpret: bool,
     n_bins: int = 4096,
     exponent: float = 1.0,
     rs_exponent: float | None = None,
@@ -221,8 +238,6 @@ def sim_sweep_pallas(
     k: int = 8,
     bm: int = 256,
     bn: int = 256,
-    bin_chunk: int = 128,
-    interpret: bool = True,
     compute_dtype=jnp.float32,
 ):
     """Fused pass: returns (block_counts (M/bm, n_bins) i32, vals (M, k) f32,
@@ -232,45 +247,22 @@ def sim_sweep_pallas(
     ``exponent``, ``v`` to ones — pass zeros in padded columns)."""
     m, d = e1.shape
     n, _ = e2.shape
-    assert m % bm == 0 and n % bn == 0, "pad inputs to block multiples"
-    assert k <= bn
-    plan = plan_bins(n_bins, bm * bn, bin_chunk)
-    if scale is None:
-        scale = jnp.ones((m, 1), jnp.float32)
-    else:
-        scale = scale.reshape(m, 1).astype(jnp.float32)
-    if v is None:
-        v = jnp.ones((1, n), jnp.float32)
-    else:
-        v = v.reshape(1, n).astype(jnp.float32)
-    rs_exp = exponent if rs_exponent is None else rs_exponent
-    grid = (m // bm, n // bn)
-    out_specs, out_shape, scratch = _out_shapes(m, n_bins, k, bm)
-    return pl.pallas_call(
-        functools.partial(
-            _kernel, n_bins=n_bins, exponent=exponent, rs_exponent=rs_exp,
-            floor=floor, k=k, bn=bn, n_blocks=n // bn, plan=plan,
-            compute_dtype=compute_dtype,
-        ),
-        grid=grid,
-        in_specs=[
+    scale, v = _row_col_operands(m, n, scale, v)
+    return _sweep_call(
+        functools.partial(_kernel, compute_dtype=compute_dtype),
+        (e1, e2, scale, v),
+        [
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(e1, e2, scale, v)
+        m=m, n=n, n_bins=n_bins, exponent=exponent, rs_exponent=rs_exponent,
+        floor=floor, k=k, bm=bm, bn=bn, interpret=interpret,
+    )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_bins", "exponent", "rs_exponent", "floor", "k", "bm",
-                     "bn", "bin_chunk", "interpret"),
-)
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def sim_sweep_q_pallas(
     q1: jax.Array,
     q2: jax.Array,
@@ -278,6 +270,8 @@ def sim_sweep_q_pallas(
     rs2: jax.Array,
     scale: jax.Array | None = None,
     v: jax.Array | None = None,
+    *,
+    interpret: bool,
     n_bins: int = 4096,
     exponent: float = 1.0,
     rs_exponent: float | None = None,
@@ -285,34 +279,16 @@ def sim_sweep_q_pallas(
     k: int = 8,
     bm: int = 256,
     bn: int = 256,
-    bin_chunk: int = 128,
-    interpret: bool = True,
 ):
     """int8 fast path: ``scores = (q1 @ q2^T) * rs1 * rs2^T`` with int32 MXU
     accumulation.  ``rs1`` is (M, 1) and ``rs2`` is (1, N) f32 row scales."""
     m, d = q1.shape
     n, _ = q2.shape
-    assert m % bm == 0 and n % bn == 0, "pad inputs to block multiples"
-    assert k <= bn
-    plan = plan_bins(n_bins, bm * bn, bin_chunk)
-    if scale is None:
-        scale = jnp.ones((m, 1), jnp.float32)
-    else:
-        scale = scale.reshape(m, 1).astype(jnp.float32)
-    if v is None:
-        v = jnp.ones((1, n), jnp.float32)
-    else:
-        v = v.reshape(1, n).astype(jnp.float32)
-    rs_exp = exponent if rs_exponent is None else rs_exponent
-    grid = (m // bm, n // bn)
-    out_specs, out_shape, scratch = _out_shapes(m, n_bins, k, bm)
-    return pl.pallas_call(
-        functools.partial(
-            _kernel_q, n_bins=n_bins, exponent=exponent, rs_exponent=rs_exp,
-            floor=floor, k=k, bn=bn, n_blocks=n // bn, plan=plan,
-        ),
-        grid=grid,
-        in_specs=[
+    scale, v = _row_col_operands(m, n, scale, v)
+    return _sweep_call(
+        _kernel_q,
+        (q1, q2, scale, rs1.reshape(m, 1), rs2.reshape(1, n), v),
+        [
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
@@ -320,8 +296,6 @@ def sim_sweep_q_pallas(
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(q1, q2, scale, rs1.reshape(m, 1), rs2.reshape(1, n), v)
+        m=m, n=n, n_bins=n_bins, exponent=exponent, rs_exponent=rs_exponent,
+        floor=floor, k=k, bm=bm, bn=bn, interpret=interpret,
+    )

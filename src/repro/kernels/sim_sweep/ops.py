@@ -20,10 +20,12 @@ Chain callers sweep many left blocks against one fixed right table: build a
 ``right=`` so padding/quantisation/upload of the right side happen once, not
 per prefix block.
 
-Block shapes route through :mod:`repro.kernels.autotune` on compiled
-backends (the tuned (bm, bn) schedule is cached on disk next to the index
-store); on CPU/interpret the historical power-of-two defaults are used
-unchanged.
+Block shapes route through :mod:`repro.kernels.autotune` on a TPU (the
+tuned (bm, bn) schedule is cached on disk next to the index store); under
+the interpreter the power-of-two defaults of
+:func:`repro.kernels.padding.row_block` / ``col_block`` apply.  Whether the
+kernel is interpreted is decided once, by
+:func:`repro.kernels.interpret_mode`.
 """
 from typing import NamedTuple, Optional
 
@@ -31,16 +33,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import autotune
-from ..padding import pad_rows, remove_pad_counts
+from .. import autotune, interpret_mode
+from ..padding import col_block, pad_rows, remove_pad_counts, row_block
 from .kernel import sim_sweep_pallas, sim_sweep_q_pallas
 from .ref import sim_sweep_ref  # noqa: F401  (oracle for tests/benchmarks)
 
 PRECISIONS = ("fp32", "bf16", "int8")
-
-
-def _pow2_block(block, n):
-    return min(block, max(8, 1 << (n - 1).bit_length()))
 
 
 class PreparedRight(NamedTuple):
@@ -71,11 +69,9 @@ def prepare_right(e2, block=256, precision="fp32",
     assert precision in PRECISIONS, precision
     e2 = np.asarray(e2, np.float32)
     n2 = e2.shape[0]
-    bn = _pow2_block(block, n2)
     sched = autotune.schedule("sim_sweep", n1_hint or n2, n2, e2.shape[1],
                               precision)
-    if sched is not None:
-        bn = _pow2_block(sched[1], n2)
+    bn = col_block(block if sched is None else sched[1], n2)
     e2p, p2 = pad_rows(e2, bn)
     q2 = rs2 = None
     if precision == "int8":
@@ -88,7 +84,7 @@ def prepare_right(e2, block=256, precision="fp32",
 
 
 def sim_sweep(e1, e2=None, n_bins=4096, exponent=1.0, floor=1e-3, k=8,
-              block=256, interpret=None, scale=None, precision="fp32",
+              block=256, scale=None, precision="fp32",
               right: Optional[PreparedRight] = None, back_v=None,
               rs_exponent=None) -> SweepOut:
     """``back_v`` (optional, (n2,) f32) is the backward chain vector applied
@@ -102,13 +98,9 @@ def sim_sweep(e1, e2=None, n_bins=4096, exponent=1.0, floor=1e-3, k=8,
         assert e2 is not None, "pass e2 or a PreparedRight"
         right = prepare_right(e2, block, precision, n1_hint=n1)
     assert right.precision == precision, (right.precision, precision)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n2 = right.n2
-    bm = _pow2_block(block, n1)
     sched = autotune.schedule("sim_sweep", n1, n2, e1.shape[1], precision)
-    if sched is not None:
-        bm = _pow2_block(sched[0], n1)
+    bm = row_block(block if sched is None else sched[0], n1)
     bn = right.bn
     e1p, p1 = pad_rows(e1, bm)
     s = np.ones(n1, np.float32) if scale is None else np.asarray(scale, np.float32)
@@ -119,7 +111,7 @@ def sim_sweep(e1, e2=None, n_bins=4096, exponent=1.0, floor=1e-3, k=8,
     vp[:n2] = 1.0 if back_v is None else np.asarray(back_v, np.float32)
     kk = min(k, bn)
     common = dict(n_bins=n_bins, exponent=exponent, rs_exponent=rs_exponent,
-                  floor=floor, k=kk, bm=bm, bn=bn, interpret=interpret)
+                  floor=floor, k=kk, bm=bm, bn=bn, interpret=interpret_mode())
     if precision == "int8":
         from repro.core.similarity import quantize_rows_int8
 

@@ -2,16 +2,32 @@
 import jax
 import jax.numpy as jnp
 
-from .kernel import comp_block_sum
+from .kernel import two_sum
+
+
+def comp_sum_ref(x):
+    """Pure-jnp error-free pairwise reduction along axis 1: (hi, lo) column
+    vectors with ``sum(x, axis=1) == hi + lo`` to ~1 ulp."""
+    hi = x
+    lo = jnp.zeros_like(x)
+    while hi.shape[1] > 1:
+        if hi.shape[1] % 2:  # pad one zero column so the halves line up
+            hi = jnp.concatenate([hi, jnp.zeros_like(hi[:, :1])], axis=1)
+            lo = jnp.concatenate([lo, jnp.zeros_like(lo[:, :1])], axis=1)
+        half = hi.shape[1] // 2
+        s, e = two_sum(hi[:, :half], hi[:, half:])
+        lo = lo[:, :half] + lo[:, half:] + e
+        hi = s
+    return hi, lo
 
 
 def sim_sweep_ref(e1, e2, n_bins=4096, exponent=1.0, floor=1e-3, k=8,
                   bm=None, scale=None, v=None, rs_exponent=None):
     """Returns (block_counts (M/bm, n_bins) i32, vals (M, k) f32,
     idx (M, k) i32, row_sums (M, 1) f32) — the same quadruple as
-    ``sim_sweep_pallas``.  Row sums use the same compensated pairwise
-    reduction as the kernel (here over the full width in one block), so the
-    oracle matches both the kernel and a float64 reference to ~1 ulp."""
+    ``sim_sweep_pallas``.  Row sums use a compensated pairwise reduction
+    over the full width (:func:`comp_sum_ref`), so the oracle matches both
+    the kernel and a float64 reference to ~1 ulp."""
     m = e1.shape[0]
     bm = m if bm is None else bm
     scores = jnp.dot(
@@ -33,5 +49,5 @@ def sim_sweep_ref(e1, e2, n_bins=4096, exponent=1.0, floor=1e-3, k=8,
     wr = base if rs_exp == 1.0 else base**rs_exp
     if v is not None:
         wr = wr * v.reshape(1, -1).astype(jnp.float32)
-    hi, lo = comp_block_sum(wr)
+    hi, lo = comp_sum_ref(wr)
     return bc, vals, top_i.astype(jnp.int32), hi + lo

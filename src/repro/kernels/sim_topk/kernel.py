@@ -18,6 +18,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+_NO_COL = 2**31 - 1
+# f32 scores at full f32: Mosaic runs a default-precision f32 dot as one
+# bf16 pass (~5e-4 error on unit vectors, measured on a v5e)
+F32_DOT = jax.lax.Precision.HIGHEST
+
+
+def merge_topk(run_v, run_i, sc, col, k: int):
+    """Top-k of the running candidates ``(run_v, run_i)`` (bm, k) and one
+    block of clipped scores ``sc`` with column ids ``col`` (bm, bn).
+
+    k extract-max passes; each takes the row maximum over both operands and,
+    among equal values, the lowest column id — the order a stable sort by
+    (value desc, column asc) gives, so the result does not depend on the
+    block width.  Scores are clipped to [0, 1], so a NEG slot never wins
+    while k <= bn.  Reductions and selects only: no lane concatenation, no
+    argmax and no scatter, which Mosaic does not lower for these shapes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
+
+    def extract(t, carry):
+        run_v, sc, new_v, new_i = carry
+        m = jnp.maximum(jnp.max(run_v, axis=1, keepdims=True),
+                        jnp.max(sc, axis=1, keepdims=True))
+        c = jnp.minimum(
+            jnp.min(jnp.where(run_v == m, run_i, _NO_COL), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(sc == m, col, _NO_COL), axis=1, keepdims=True),
+        )
+        return (jnp.where((run_v == m) & (run_i == c), NEG, run_v),
+                jnp.where((sc == m) & (col == c), NEG, sc),
+                jnp.where(lane == t, m, new_v),
+                jnp.where(lane == t, c, new_i))
+
+    init = (run_v, sc, jnp.full(run_v.shape, NEG, jnp.float32),
+            jnp.zeros(run_i.shape, jnp.int32))
+    _, _, new_v, new_i = jax.lax.fori_loop(0, k, extract, init)
+    return new_v, new_i
 
 
 def _kernel(e1_ref, e2_ref, vals_ref, idx_ref, run_v, run_i, *, k: int,
@@ -32,28 +68,12 @@ def _kernel(e1_ref, e2_ref, vals_ref, idx_ref, run_v, run_i, *, k: int,
     e1 = e1_ref[...].astype(jnp.float32)
     e2 = e2_ref[...].astype(jnp.float32)
     scores = jax.lax.dot_general(
-        e1, e2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        e1, e2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT,
     )
     scores = jnp.clip(scores, 0.0, 1.0)
-    bm = scores.shape[0]
-    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
-
-    cand_v = jnp.concatenate([run_v[...], scores], axis=1)    # (bm, k+bn)
-    cand_i = jnp.concatenate([run_i[...], col], axis=1)
-    width = k + bn
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bm, width), 1)
-
-    new_v = jnp.full((bm, k), NEG, jnp.float32)
-    new_i = jnp.zeros((bm, k), jnp.int32)
-    for t in range(k):  # k extract-max passes (k is static and small)
-        m = jnp.max(cand_v, axis=1)                            # (bm,)
-        am = jnp.argmax(cand_v, axis=1).astype(jnp.int32)      # (bm,)
-        sel = iota == am[:, None]
-        picked_i = jnp.sum(jnp.where(sel, cand_i, 0), axis=1)
-        new_v = new_v.at[:, t].set(m)
-        new_i = new_i.at[:, t].set(picked_i)
-        cand_v = jnp.where(sel, NEG, cand_v)
-
+    col = j * bn + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    new_v, new_i = merge_topk(run_v[...], run_i[...], scores, col, k)
     run_v[...] = new_v
     run_i[...] = new_i
 
@@ -69,10 +89,11 @@ def _kernel(e1_ref, e2_ref, vals_ref, idx_ref, run_v, run_i, *, k: int,
 def sim_topk_pallas(
     e1: jax.Array,
     e2: jax.Array,
+    *,
+    interpret: bool,
     k: int = 8,
     bm: int = 256,
     bn: int = 256,
-    interpret: bool = True,
 ):
     m, d = e1.shape
     n, _ = e2.shape
@@ -99,4 +120,5 @@ def sim_topk_pallas(
             pltpu.VMEM((bm, k), jnp.int32),
         ],
         interpret=interpret,
+        name="sim_topk",
     )(e1, e2)

@@ -1,28 +1,23 @@
 """Public op: per-left-row top-k similar right rows (NN blocking)."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret_mode
+from ..padding import col_block, pad_rows, row_block
 from .kernel import sim_topk_pallas
 from .ref import sim_topk_ref  # noqa: F401
 
 
-def sim_topk(e1, e2, k=8, block=256, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def sim_topk(e1, e2, k=8, block=256):
     e1 = np.asarray(e1, np.float32)
     e2 = np.asarray(e2, np.float32)
     n1, n2 = e1.shape[0], e2.shape[0]
-    bm = min(block, max(8, 1 << (n1 - 1).bit_length()))
-    bn = min(block, max(8, 1 << (n2 - 1).bit_length()))
-    p1, p2 = (-n1) % bm, (-n2) % bn
-    if p1:
-        e1 = np.concatenate([e1, np.zeros((p1, e1.shape[1]), e1.dtype)])
-    if p2:
-        e2 = np.concatenate([e2, np.full((p2, e2.shape[1]), 0.0, e2.dtype)])
+    bm, bn = row_block(block, n1), col_block(block, n2)
+    e1, _ = pad_rows(e1, bm)
+    e2, _ = pad_rows(e2, bn)
     vals, idx = sim_topk_pallas(
         jnp.asarray(e1), jnp.asarray(e2), k=min(k, bn), bm=bm, bn=bn,
-        interpret=interpret,
+        interpret=interpret_mode(),
     )
     vals, idx = np.asarray(vals)[:n1], np.asarray(idx)[:n1]
     # drop hits pointing at padded right rows (score 0 ties)

@@ -10,33 +10,20 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes, **kwargs):
-    """Version-tolerant ``jax.make_mesh``.
-
-    Newer JAX accepts (and some idioms pass) ``axis_types``; older releases
-    expose neither ``jax.sharding.AxisType`` nor the keyword.  Always request
-    Auto axes when the installed JAX supports them, otherwise fall back to the
-    plain call (Auto is the default there anyway).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes), **kwargs
-            )
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
+def _auto(axes) -> tuple:
+    """Auto sharding for every axis: the compiler propagates shardings."""
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Debug mesh over whatever devices exist (tests, examples)."""
     n = len(jax.devices())
     data = n // model
-    return make_mesh((data, model), ("data", "model"))
+    axes = ("data", "model")
+    return jax.make_mesh((data, model), axes, axis_types=_auto(axes))

@@ -10,6 +10,9 @@ In-process modes::
         --mode score --pairs 64
     PYTHONPATH=src python -m repro.launch.serve --arch joinml-oracle \
         --mode service --queries 4 --budget 300
+    # the scorer at its published widths (random weights from --seed)
+    PYTHONPATH=src python -m repro.launch.serve --arch joinml-oracle \
+        --width published --mode score --pairs 256
 
 Multi-host modes (see docs/serving.md for the topology)::
 
@@ -57,29 +60,61 @@ warm queries from these artifacts.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import numpy as np
 
 
-def _make_scorer(args, cfg, params, tok, records, batch_size: int):
-    """Shared scorer construction for the score/service modes: record-pair
-    tokenizer + optional data-parallel mesh sharding (--shard)."""
+PAIR_LEN = 48  # tokens per serialised record pair
+
+
+def load_model(arch: str, width: str = "smoke", seed: int = 0):
+    """Model for the scoring/decode modes: ``width="published"`` builds the
+    architecture at its published widths (``repro.configs.get_config``),
+    ``"smoke"`` the reduced CPU-sized variant.  Weights come from
+    ``init_params`` at ``seed``.  Returns ``(cfg, params, tokenizer)``."""
+    import jax
+
+    from repro.configs import get_config, get_smoke_config
+    from repro.data.pipeline import ByteTokenizer
+    from repro.models import init_params
+
+    tok = ByteTokenizer()
+    if width == "published":
+        cfg = get_config(arch)
+        if cfg.vocab_size < tok.vocab_size:
+            raise ValueError(f"{arch}: vocabulary of {cfg.vocab_size} cannot "
+                             f"hold the {tok.vocab_size} byte-tokenizer ids")
+    else:
+        cfg = get_smoke_config(arch, vocab_size=tok.vocab_size)
+    # one compiled program instead of one dispatch per parameter op
+    params = jax.jit(functools.partial(init_params, cfg))(jax.random.key(seed))
+    return cfg, params, tok
+
+
+def make_scorer(cfg, params, tok, left: list, right: list, batch_size: int,
+                shard: bool = False):
+    """Scorer for the score/service/fleet modes: a pair ``(i, j)`` is the
+    prompt over ``left[i]`` and ``right[j]`` — one record list per table, so
+    tables of any sizes join.  ``shard`` spreads every score batch over all
+    host devices (data-parallel, ``--shard``)."""
     from repro.data.pipeline import pair_example
     from repro.serve.serve_loop import PairScorer
 
     def tok_pair(pair):
-        t, _ = pair_example(tok, records[pair[0]], records[pair[1]], None, 48)
+        t, _ = pair_example(tok, left[pair[0]], right[pair[1]], None,
+                            PAIR_LEN)
         return t[t != tok.PAD]
 
     mesh = None
-    if args.shard:
+    if shard:
         from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh()
         print(f"[serve] sharding score batches over mesh {dict(mesh.shape)}")
-    return PairScorer(cfg, params, tok_pair, tok.YES, tok.NO, max_len=48,
-                      batch_size=batch_size, mesh=mesh)
+    return PairScorer(cfg, params, tok_pair, tok.YES, tok.NO,
+                      max_len=PAIR_LEN, batch_size=batch_size, mesh=mesh)
 
 
 def _run_client(args) -> None:
@@ -326,6 +361,12 @@ def _run_fleet_role(args, scorer) -> None:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
+    ap.add_argument("--width", choices=("smoke", "published"),
+                    default="smoke",
+                    help="model widths: the reduced smoke config or the "
+                         "architecture's published config")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the randomly initialised weights")
     ap.add_argument("--mode",
                     choices=("decode", "score", "service",
                              "server", "client", "worker",
@@ -410,16 +451,11 @@ def main():
         _run_refresh_index(args)
         return
 
-    import jax
-
-    from repro.configs import get_smoke_config
-    from repro.data.pipeline import ByteTokenizer
-    from repro.models import init_params
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve.serve_loop import ContinuousBatcher, Request
 
-    tok = ByteTokenizer()
-    cfg = get_smoke_config(args.arch, vocab_size=tok.vocab_size)
-    params = init_params(cfg, jax.random.key(0))
+    enable_compile_cache()
+    cfg, params, tok = load_model(args.arch, args.width, args.seed)
     print(f"[serve] {cfg.name} ({cfg.param_count()/1e6:.1f}M) mode={args.mode}")
 
     if args.mode == "decode":
@@ -441,7 +477,8 @@ def main():
     elif args.mode in ("server", "worker"):
         n_side = args.n_side
         records = [f"entity record {i:03d}" for i in range(n_side)]
-        scorer = _make_scorer(args, cfg, params, tok, records, batch_size=32)
+        scorer = make_scorer(cfg, params, tok, records, records,
+                             batch_size=32, shard=args.shard)
         _run_fleet_role(args, scorer)
     elif args.mode == "service":
         from repro.core import Agg, BASConfig, ModelOracle, Query, run_bas
@@ -452,7 +489,8 @@ def main():
         ds = make_clustered_tables(n_side, n_side, n_entities=64, noise=0.4,
                                    seed=0)
         records = [f"entity record {i:03d}" for i in range(n_side)]
-        scorer = _make_scorer(args, cfg, params, tok, records, batch_size=32)
+        scorer = make_scorer(cfg, params, tok, records, records,
+                             batch_size=32, shard=args.shard)
         cfg_bas = BASConfig(n_bootstrap=100)
         # named oracles share one LabelStore segment group (an unnamed
         # ModelOracle's group is process-local and can never be persisted)
@@ -514,7 +552,8 @@ def main():
                   f"calls={oracles[i].calls}")
     else:
         records = [f"entity {i % 16} record {i}" for i in range(64)]
-        scorer = _make_scorer(args, cfg, params, tok, records, batch_size=16)
+        scorer = make_scorer(cfg, params, tok, records, records,
+                             batch_size=16, shard=args.shard)
         rng = np.random.default_rng(0)
         pairs = rng.integers(0, 64, size=(args.pairs, 2))
         t0 = time.time()

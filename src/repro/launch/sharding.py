@@ -181,17 +181,6 @@ def shard_activation(x: jax.Array, logical: Sequence[Optional[str]]):
     return jax.lax.with_sharding_constraint(x, NamedSharding(_CTX.mesh, spec))
 
 
-def _shard_map_fn():
-    """Version-tolerant shard_map entry point (jax.shard_map when present,
-    jax.experimental.shard_map.shard_map otherwise)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def mesh_batch_axes(mesh: "Mesh", rules: Optional[Rules] = None) -> tuple:
     """Mesh axes the batch dimension maps to under ``rules`` (no context
     needed — used by the serving layer to size device-sharded score batches)."""
@@ -217,12 +206,8 @@ def data_parallel(fn, mesh: "Mesh", rules: Optional[Rules] = None):
     if not axes:
         return fn
     spec = P(axes if len(axes) > 1 else axes[0])
-    sm = _shard_map_fn()
-    try:
-        return sm(fn, mesh=mesh, in_specs=(P(), spec), out_specs=spec,
-                  check_rep=False)
-    except TypeError:  # newer jax renamed/removed check_rep
-        return sm(fn, mesh=mesh, in_specs=(P(), spec), out_specs=spec)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(), spec), out_specs=spec,
+                         check_vma=False)
 
 
 def tree_shardings(specs_tree, shapes_tree, mesh=None, rules=None):

@@ -58,7 +58,14 @@ class PairScorer:
         self.mesh = mesh
         self.forward_batches = 0   # compiled forward invocations
         self.pairs_scored = 0
-        fwd = lambda p, b: forward(cfg, p, b)  # noqa: E731
+
+        def fwd(p, b):
+            # [yes, no] logits at each row's last real position, gathered on
+            # the device so only (B, 2) values come back to the host
+            logits = forward(cfg, p, {"tokens": b["tokens"]})
+            last = logits[jnp.arange(logits.shape[0]), b["last"]]
+            return jnp.stack([last[:, yes_id], last[:, no_id]], axis=1)
+
         if mesh is not None:
             shards = mesh_batch_shards(mesh)
             batch_size = -(-batch_size // shards) * shards
@@ -74,6 +81,18 @@ class PairScorer:
             b *= 2
         buckets.append(max_len)
         self._buckets = np.array(buckets, np.int64)
+
+    def yes_no_logits(self, toks: np.ndarray, last: np.ndarray) -> jax.Array:
+        """Device (B, 2) [yes, no] logits of one padded block (``B`` =
+        ``batch_size``); sharded over the batch axes under ``mesh``."""
+        self.forward_batches += 1
+        return self._fwd(self.params, {"tokens": jnp.asarray(toks),
+                                       "last": jnp.asarray(last)})
+
+    def encode(self, pairs: np.ndarray, pad_len: int) -> tuple:
+        """Tokenize and pad ``pairs`` -> ((n, pad_len) tokens, (n,) last
+        real position)."""
+        return self._pad_block(self._tokenize(np.asarray(pairs)), pad_len)
 
     def _tokenize(self, pairs: np.ndarray) -> list:
         return [
@@ -116,12 +135,7 @@ class PairScorer:
                         [toks, np.zeros((pad_rows, int(pad_len)), np.int32)]
                     )
                     last = np.concatenate([last, np.zeros(pad_rows, np.int32)])
-                logits = self._fwd(self.params, {"tokens": jnp.asarray(toks)})
-                self.forward_batches += 1
-                lg = np.asarray(
-                    logits[np.arange(bs), last, :][:, [self.yes_id, self.no_id]],
-                    np.float64,
-                )
+                lg = np.asarray(self.yes_no_logits(toks, last), np.float64)
                 out[idxs] = _stable_yes_no_prob(lg)[: len(idxs)]
         self.pairs_scored += n
         return out

@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models import loss_fn
 from repro.models.config import ModelConfig
@@ -70,12 +69,12 @@ def make_manual_dp_train_step(
         loss = jax.lax.psum(loss, dp_axes) / n
         return loss, grads
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(), batch_spec),   # prefix specs: params replicated,
         out_specs=(P(), P()),         # batch leaves sharded on dim 0
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

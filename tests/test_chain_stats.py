@@ -5,7 +5,7 @@ single blocked sweep additionally emits per-row walk sums and the chain
 total weight, accumulated in compensated (two-float) f32 — and those agree
 with the f64 numpy reference to 1e-6 relative even on adversarial magnitude
 spreads, for both the Pallas kernel (interpret on CPU) and the blocked
-numpy fallback.  Downstream: walk setup consumes the fused statistics, so a
+numpy sweep.  Downstream: walk setup consumes the fused statistics, so a
 warm-index (or cold fused-sweep) streaming query launches ZERO standalone
 passes over the cross product — asserted via the pass-launch counters in
 ``repro.core.similarity.PASS_COUNTS``.
@@ -49,15 +49,55 @@ def _spread_v(rng, n, decades=4.0):
     return (10.0 ** rng.uniform(-decades, decades, n)).astype(np.float32)
 
 
+def _kernel_f32_weights(e1, e2, exponent, floor, block):
+    """The clipped f32 scores and f32 weights the sweep kernel computes for
+    ``sim_sweep(..., block=block)``: the same dot on the same (bm, d) x
+    (bn, d) blocks — the Pallas interpreter runs that very XLA op — and the
+    same f32 transform."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.padding import col_block, pad_rows, row_block
+    from repro.kernels.sim_topk.kernel import F32_DOT
+
+    dot = jax.jit(lambda a, b: jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=F32_DOT))
+    n1, n2 = len(e1), len(e2)
+    bm, bn = row_block(block, n1), col_block(block, n2)
+    a, _ = pad_rows(e1, bm)
+    b, _ = pad_rows(e2, bn)
+    s = np.concatenate([
+        np.concatenate([np.asarray(dot(a[i:i + bm], b[j:j + bn]))
+                        for j in range(0, len(b), bn)], axis=1)
+        for i in range(0, len(a), bm)
+    ])[:n1, :n2]
+    s = jnp.clip(jnp.asarray(s), 0.0, 1.0)
+    base = jnp.maximum(s, floor)
+    w = base if exponent == 1.0 else base**exponent
+    return np.asarray(s), np.asarray(w)
+
+
 def _check_fused_pair_sums(seed, n1, n2, d, exponent, floor, decades):
-    """Fused kernel sums vs the f64 reference, one pair sweep."""
+    """Fused kernel sums vs the f64 sum of the kernel's own f32 weights.
+
+    The kernel scores and transforms in f32; at exponent p a score's
+    rounding moves its weight by p times as much, so an f64 reference would
+    charge the accumulation for the matmul's and the power's rounding.  The
+    reference therefore recomputes the kernel's f32 weights
+    (:func:`_kernel_f32_weights`; the sweep's top-k values must equal its
+    scores exactly, checked) and sums them in f64: what is left to differ
+    is the compensated accumulation, held to 1e-6 relative."""
     rng = np.random.default_rng(seed)
     e1, e2 = _unit_rows(rng, n1, d), _unit_rows(rng, n2, d)
     v = _spread_v(rng, n2, decades)
     out = sim_sweep(e1, e2, n_bins=64, exponent=exponent, floor=floor,
                     block=64, back_v=v)
-    w64 = pair_weights(e1, e2, exponent, floor)
-    ref = (w64 * v.astype(np.float64)).sum(axis=1)
+    s32, w32 = _kernel_f32_weights(e1, e2, exponent, floor, block=64)
+    rows = np.nonzero(out.valid)[0]
+    np.testing.assert_array_equal(out.vals[out.valid],
+                                  s32[rows, out.idx[out.valid]])
+    ref = (w32.astype(np.float64) * v.astype(np.float64)).sum(axis=1)
     np.testing.assert_allclose(out.row_sums, ref, rtol=REL_TOL)
 
 
@@ -131,8 +171,11 @@ def test_naive_f32_fails_where_compensated_passes():
     """The regression the compensated accumulator exists for: one large
     summand followed by thousands of small ones.  A running f32 sum loses
     the entire small mass (each add rounds to nothing against the large
-    partial); the kernel's two-float pairwise reduction keeps it."""
+    partial); the kernel's two-float reduction (run inside a kernel, where
+    its lane rotations lower) keeps it."""
+    import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
     from repro.kernels.sim_sweep.kernel import comp_block_sum
 
@@ -145,7 +188,12 @@ def test_naive_f32_fails_where_compensated_passes():
         naive = np.float32(naive + x)
     assert abs(float(naive) - ref) / ref > REL_TOL     # naive f32 fails
 
-    hi, lo = comp_block_sum(jnp.asarray(vals)[None, :])
+    def kernel(x_ref, hi_ref, lo_ref):
+        hi_ref[...], lo_ref[...] = comp_block_sum(x_ref[...])
+
+    tile = jax.ShapeDtypeStruct((8, 128), jnp.float32)
+    x = jnp.broadcast_to(jnp.asarray(vals), (8, 4096))
+    hi, lo = pl.pallas_call(kernel, out_shape=(tile, tile), interpret=True)(x)
     comp = float(np.asarray(hi)[0, 0]) + float(np.asarray(lo)[0, 0])
     assert abs(comp - ref) / ref < 1e-9                # compensated passes
 
@@ -154,14 +202,20 @@ def test_fused_sweep_absorbs_adversarial_back_vector():
     """End-to-end version of the regression: the same large/small spread
     arriving through the backward chain vector still meets the 1e-6 rel
     contract inside the fused sweep (cross-block carry is compensated too).
-    """
+
+    Every score is ~1, the first column carries 2**26 and the other 16,383
+    carry 0.03: each 128-column block adds ~3.8 to a running total whose
+    half-ulp is 4, so an uncompensated carry drops every block after the
+    first (~7e-6 relative) while the compensated one keeps them all."""
     rng = np.random.default_rng(9)
-    e1, e2 = _unit_rows(rng, 40, 16), _unit_rows(rng, 1500, 16)
-    v = np.ones(1500, np.float32)
-    v[0] = np.float32(1e8)
+    u = _unit_rows(rng, 1, 16)
+    e1, e2 = np.repeat(u, 40, axis=0), np.repeat(u, 128 * 128, axis=0)
+    v = np.full(len(e2), 0.03, np.float32)
+    v[0] = np.float32(2**26)
     out = sim_sweep(e1, e2, n_bins=64, exponent=1.0, floor=1e-3,
                     block=64, back_v=v)
-    ref = (pair_weights(e1, e2, 1.0, 1e-3) * v.astype(np.float64)).sum(axis=1)
+    _, w32 = _kernel_f32_weights(e1, e2, 1.0, 1e-3, block=64)
+    ref = (w32.astype(np.float64) * v.astype(np.float64)).sum(axis=1)
     np.testing.assert_allclose(out.row_sums, ref, rtol=REL_TOL)
 
 
@@ -259,7 +313,7 @@ def test_index_persists_and_appends_fused_sums(tmp_path):
 
 
 # ----------------------------------------------------------------------------
-# autotuner: compiled-only, cached on disk, routed into the ops
+# autotuner: TPU-only, cached on disk, routed into the ops
 # ----------------------------------------------------------------------------
 
 def test_autotune_schedule(tmp_path, monkeypatch):
@@ -268,8 +322,12 @@ def test_autotune_schedule(tmp_path, monkeypatch):
     autotune.reset()
     try:
         # CPU / interpret mode: no measurement, no behaviour change
-        assert autotune.schedule("sim_sweep", 512, 512, 32,
-                                 backend="cpu") is None
+        assert autotune.schedule("sim_sweep", 512, 512, 32) is None
+        # an unverified device kind raises instead of guessing a schedule
+        with pytest.raises(RuntimeError, match="device kind"):
+            autotune.schedule("sim_sweep", 512, 512, 32,
+                              device_kind="TPU v9 hypothetical")
+        kind = autotune.DEVICE_KINDS[0]
 
         calls = []
 
@@ -280,12 +338,12 @@ def test_autotune_schedule(tmp_path, monkeypatch):
         monkeypatch.setattr(autotune, "_measure", fake_measure)
         autotune.configure(tmp_path / "autotune.json")
 
-        won = autotune.schedule("sim_sweep", 300, 500, 32, backend="tpu")
+        won = autotune.schedule("sim_sweep", 300, 500, 32, device_kind=kind)
         assert won in autotune.CANDIDATES
         assert len(calls) == 1
         # same shape bucket: served from memory, no re-measurement
         assert autotune.schedule("sim_sweep", 280, 510, 32,
-                                 backend="tpu") == won
+                                 device_kind=kind) == won
         assert len(calls) == 1
 
         # the winner persisted — a fresh process (reset) rereads the disk
@@ -293,7 +351,7 @@ def test_autotune_schedule(tmp_path, monkeypatch):
         autotune.reset()
         autotune.configure(tmp_path / "autotune.json")
         assert autotune.schedule("sim_sweep", 300, 500, 32,
-                                 backend="tpu") == won
+                                 device_kind=kind) == won
         assert len(calls) == 1
     finally:
         autotune.reset()
@@ -310,8 +368,9 @@ def test_index_store_configures_autotune_cache(tmp_path, monkeypatch):
         monkeypatch.setattr(autotune, "_measure",
                             lambda *a: autotune.CANDIDATES[0])
         IndexStore(root=tmp_path)
-        assert autotune.schedule("sim_sweep", 128, 128, 32,
-                                 backend="tpu") == autotune.CANDIDATES[0]
+        assert autotune.schedule(
+            "sim_sweep", 128, 128, 32, device_kind=autotune.DEVICE_KINDS[0]
+        ) == autotune.CANDIDATES[0]
         assert (tmp_path / "autotune.json").exists()
     finally:
         autotune.reset()

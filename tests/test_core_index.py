@@ -9,7 +9,7 @@ The acceptance contract this file pins down:
   queries, including after a save -> mmap-load round trip;
 * :func:`~repro.core.index.append_rows` equals a full recompute **exactly**
   (integer tiles, merged top-k, re-derived content key) over random append
-  splits, on the fp32 numpy fallback, the fp32 kernel path, and the int8
+  splits, on the fp32 numpy sweep, the fp32 kernel path, and the int8
   kernel path — the property the paper's build-once/query-many economics
   rest on;
 * the content key tracks exactly the quantities that change sweep output
@@ -268,13 +268,13 @@ def test_append_equals_full_recompute_int8():
 
 
 def test_append_lowp_without_kernel_refuses():
-    """A lowp artifact whose delta could only run the fp32 numpy fallback
+    """A lowp artifact whose delta could only run the fp32 numpy sweep
     must refuse rather than silently mix precisions across tiles."""
     e1, e2 = _tables(64, 64)
     art = _build([e1, e2], use_kernel=True, precision="int8",
                  tolerance=float("inf"))
     with pytest.raises(RuntimeError, match="without the sweep kernel"), \
-            pytest.warns(UserWarning, match="numpy fallback"):
+            pytest.warns(UserWarning, match="numpy sweep"):
         append_rows(art, 1, _tables(8, 8, seed=9)[1], use_kernel=False)
 
 

@@ -136,7 +136,7 @@ def _strata_identical(a, b):
 def test_sweep_bit_identical_to_two_pass(use_kernel):
     """The fused single-sweep stratification must produce *bit-identical*
     strata to the retired two-pass (histogram then collect) schedule, on
-    both the numpy fallback and the Pallas kernel path."""
+    both the numpy sweep and the Pallas kernel path."""
     rng = np.random.default_rng(21)
     e1 = normalize(rng.standard_normal((130, 16)))
     e2 = normalize(rng.standard_normal((90, 16)))
@@ -282,12 +282,12 @@ def test_unknown_precision_rejected():
 
 
 def test_low_precision_warns_when_kernel_unavailable():
-    """A bf16/int8 opt-in that can only run the numpy fallback must say so
+    """A bf16/int8 opt-in on the numpy sweep (use_kernel=False) must say so
     instead of silently computing fp32."""
     rng = np.random.default_rng(27)
     e1 = normalize(rng.standard_normal((32, 8)))
     e2 = normalize(rng.standard_normal((32, 8)))
-    with pytest.warns(UserWarning, match="numpy fallback computes fp32"):
+    with pytest.warns(UserWarning, match="numpy sweep computes fp32"):
         sw = sweep_pass(e1, e2, use_kernel=False, precision="int8")
     assert sw.precision == "fp32" and not sw.kernel
 

@@ -35,8 +35,8 @@ def test_sharding_rules_divisibility_fallback():
     code = """
 import jax
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((2, 8), ("data", "model"))
+mesh = jax.make_mesh((2, 8), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 from repro.launch.sharding import spec_for, TRAIN_RULES
 # heads=12 not divisible by model=8 -> replicated; mlp=64 divisible -> sharded
 s1 = spec_for(("batch", "seq", "heads"), (4, 16, 12), TRAIN_RULES, mesh)
@@ -54,8 +54,8 @@ print("OK")
 def test_small_mesh_cell_lowers_and_analyzer_expands():
     code = """
 import jax, json
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 from repro.launch import cells as C
 from repro.configs import get_smoke_config
 import repro.launch.cells as cells_mod

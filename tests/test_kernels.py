@@ -27,7 +27,7 @@ def test_sim_hist_matches_ref(m, n, d, dtype):
     e1, e2 = rand_emb(rng, m, d, dtype), rand_emb(rng, n, d, dtype)
     n_bins = 256
     got = sim_hist_pallas(e1, e2, n_bins=n_bins, bm=min(64, m), bn=min(64, n),
-                          bin_chunk=64, interpret=True)
+                          interpret=True)
     want = sim_hist_ref(e1, e2, n_bins=n_bins)
     assert int(got.sum()) == m * n
     # bf16 rounding can move boundary scores one bin; compare CDFs loosely
@@ -47,7 +47,7 @@ def test_sim_hist_exponent(exponent):
     rng = np.random.default_rng(1)
     e1, e2 = rand_emb(rng, 64, 16, jnp.float32), rand_emb(rng, 64, 16, jnp.float32)
     got = sim_hist_pallas(e1, e2, n_bins=128, exponent=exponent, bm=64, bn=64,
-                          bin_chunk=64, interpret=True)
+                          interpret=True)
     want = sim_hist_ref(e1, e2, n_bins=128, exponent=exponent)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

@@ -13,8 +13,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 CODE = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 from repro.configs import get_smoke_config
 from repro.models import init_params, loss_fn
 from repro.train import OptimizerConfig, init_opt_state
