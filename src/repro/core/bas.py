@@ -33,6 +33,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.obs.spans import bind, bound_timings, span
+
 from . import allocate as alloc_mod
 from .bootstrap import bootstrap_t_ci
 from .estimators import (
@@ -87,13 +89,15 @@ def _label_draws(
     Submit-then-await: the flush is submitted asynchronously and the cheap
     g(.) evaluation overlaps the labelling; with an attached OracleService
     the await is where concurrent queries' pilot/main rounds coalesce into
-    shared super-batches."""
+    shared super-batches.  The await is the ``oracle.wait`` span, summed
+    into the bound query timings' ``oracle_wait_s``."""
     batch = OracleBatch(query.oracle)
     handles = [None if d is None else batch.submit(d.tup) for d in draws]
     fut = batch.flush_async()
     g = query.attr()
     gs = [None if d is None else g(d.tup) for d in draws]
-    fut.result()
+    with span("oracle.wait", bound_timings(), "oracle_wait_s"):
+        fut.result()
     return [
         None if d is None else StratumSample(
             o=h.labels, g=gv, q=d.q, size=d.size
@@ -183,98 +187,108 @@ def run_stratified_pipeline(
     t_start: float,
 ) -> QueryResult:
     """Alg. 4 lines 6-17 on an abstract stratified space (shared by the dense
-    and streaming BAS paths)."""
+    and streaming BAS paths).  ``timings`` gets each stage's length, and the
+    sums of its ``sample`` and ``oracle.wait`` spans (``sample_s``,
+    ``oracle_wait_s``)."""
+    with bind(timings=timings):
+        return _pipeline(query, cfg, rng, space, detail, timings, t_start)
+
+
+def _pipeline(query: Query, cfg: BASConfig, rng: np.random.Generator,
+              space: StratifiedSpace, detail: dict, timings: dict,
+              t_start: float) -> QueryResult:
     sizes, weight_sums = space.sizes, space.weight_sums
     k = len(sizes) - 1
     b = query.budget
     b1 = max(int(round(cfg.pilot_fraction * b)), 8)
 
     # ---- stage 1: pilot ---------------------------------------------------
-    t0 = time.perf_counter()
-    shares = weight_sums / max(weight_sums.sum(), 1e-300)
-    n_pilot = np.maximum((shares * b1).astype(np.int64), 2)
-    while n_pilot.sum() > b1 and n_pilot.max() > 2:
-        n_pilot[np.argmax(n_pilot)] -= 1
+    with span("pilot", timings, "pilot_s"):
+        shares = weight_sums / max(weight_sums.sum(), 1e-300)
+        n_pilot = np.maximum((shares * b1).astype(np.int64), 2)
+        while n_pilot.sum() > b1 and n_pilot.max() > 2:
+            n_pilot[np.argmax(n_pilot)] -= 1
 
-    pilot_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
-    for i in range(k + 1):
-        if sizes[i] > 0:
-            pilot_draws[i] = space.sample_stratum(i, int(n_pilot[i]))
-    samples: list[Optional[StratumSample]] = _label_draws(query, pilot_draws)
+        pilot_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
+        for i in range(k + 1):
+            if sizes[i] > 0:
+                with span("sample", timings, "sample_s", stratum=i):
+                    pilot_draws[i] = space.sample_stratum(i, int(n_pilot[i]))
+        samples: list[Optional[StratumSample]] = _label_draws(query, pilot_draws)
 
-    live = [s for s in samples if s is not None]
-    c_hat, _ = combined_count(live, BlockedRegime(np.zeros(0), np.zeros(0)))
-    s_hat, _ = combined_sum(live, BlockedRegime(np.zeros(0), np.zeros(0)))
-    ratio = s_hat / c_hat if c_hat > 0 else 0.0
-    sigma2 = np.zeros(k + 1, np.float64)
-    for i in range(k + 1):
-        if samples[i] is not None:
-            sigma2[i] = _linearised_variance(samples[i], query.agg, ratio, c_hat)
-    timings["pilot_s"] = time.perf_counter() - t0
+        live = [s for s in samples if s is not None]
+        c_hat, _ = combined_count(live, BlockedRegime(np.zeros(0), np.zeros(0)))
+        s_hat, _ = combined_sum(live, BlockedRegime(np.zeros(0), np.zeros(0)))
+        ratio = s_hat / c_hat if c_hat > 0 else 0.0
+        sigma2 = np.zeros(k + 1, np.float64)
+        for i in range(k + 1):
+            if samples[i] is not None:
+                sigma2[i] = _linearised_variance(samples[i], query.agg, ratio, c_hat)
 
     # ---- allocation -------------------------------------------------------
-    t0 = time.perf_counter()
-    b2_eff = query.budget - query.oracle.calls
-    if query.agg in (Agg.MIN, Agg.MAX):
-        allocation = _allocate_extreme(samples, sizes, weight_sums, b2_eff, query.agg)
-    else:
-        allocation = alloc_mod.argmin_beta(
-            sigma2, weight_sums, sizes, b2_eff, cfg.exact_beta_max_k
-        )
-    beta = set(int(i) for i in allocation.beta)
-    timings["allocate_s"] = time.perf_counter() - t0
+    with span("allocate", timings, "allocate_s"):
+        b2_eff = query.budget - query.oracle.calls
+        if query.agg in (Agg.MIN, Agg.MAX):
+            allocation = _allocate_extreme(samples, sizes, weight_sums, b2_eff, query.agg)
+        else:
+            allocation = alloc_mod.argmin_beta(
+                sigma2, weight_sums, sizes, b2_eff, cfg.exact_beta_max_k
+            )
+        beta = set(int(i) for i in allocation.beta)
 
     # ---- stage 2: blocking + sampling -------------------------------------
-    t0 = time.perf_counter()
-    # submit-then-await: the blocking-regime labelling runs on the oracle
-    # backend (or service) while g(.) is evaluated for the same tuples here
-    block_batch = OracleBatch(query.oracle)
-    beta_tuples = [(i, space.stratum_tuples(i)) for i in sorted(beta)]
-    beta_handles = [block_batch.submit(tup) for _, tup in beta_tuples]
-    block_fut = block_batch.flush_async()
-    g_fn = query.attr()
-    blocked_g = [g_fn(tup) for _, tup in beta_tuples]
-    block_fut.result()
-    blocked_o = [h.labels for h in beta_handles]
-    blocked = BlockedRegime(
-        o=np.concatenate(blocked_o) if blocked_o else np.zeros(0),
-        g=np.concatenate(blocked_g) if blocked_g else np.zeros(0),
-    )
+    with span("execute", timings, "execute_s"):
+        # submit-then-await: the blocking-regime labelling runs on the oracle
+        # backend (or service) while g(.) is evaluated for the same tuples here
+        block_batch = OracleBatch(query.oracle)
+        beta_tuples = [(i, space.stratum_tuples(i)) for i in sorted(beta)]
+        beta_handles = [block_batch.submit(tup) for _, tup in beta_tuples]
+        block_fut = block_batch.flush_async()
+        g_fn = query.attr()
+        blocked_g = [g_fn(tup) for _, tup in beta_tuples]
+        with span("oracle.wait", timings, "oracle_wait_s"):
+            block_fut.result()
+        blocked_o = [h.labels for h in beta_handles]
+        blocked = BlockedRegime(
+            o=np.concatenate(blocked_o) if blocked_o else np.zeros(0),
+            g=np.concatenate(blocked_g) if blocked_g else np.zeros(0),
+        )
 
-    sampled_ids = [i for i in range(k + 1) if i not in beta and sizes[i] > 0]
-    rounds = 0
-    while rounds < 4:
-        remaining = query.budget - query.oracle.calls
-        if remaining < 2 * max(len(sampled_ids), 1):
-            break
-        w_s = np.array([weight_sums[i] for i in sampled_ids])
-        share = w_s / max(w_s.sum(), 1e-300)
-        n_main = np.maximum((share * remaining).astype(np.int64), 1)
-        while n_main.sum() > remaining:
-            n_main[np.argmax(n_main)] -= 1
-        before = query.oracle.calls
-        round_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
-        for j, i in enumerate(sampled_ids):
-            if n_main[j] <= 0:
-                continue
-            round_draws[i] = space.sample_stratum(i, int(n_main[j]))
-        round_samples = _label_draws(query, round_draws)
-        for i in sampled_ids:
-            new = round_samples[i]
-            if new is not None:
-                samples[i] = new if samples[i] is None else samples[i].merge(new)
-        rounds += 1
-        if query.oracle.calls == before:  # everything cached; budget cannot move
-            break
-    timings["execute_s"] = time.perf_counter() - t0
+        sampled_ids = [i for i in range(k + 1) if i not in beta and sizes[i] > 0]
+        rounds = 0
+        while rounds < 4:
+            remaining = query.budget - query.oracle.calls
+            if remaining < 2 * max(len(sampled_ids), 1):
+                break
+            w_s = np.array([weight_sums[i] for i in sampled_ids])
+            share = w_s / max(w_s.sum(), 1e-300)
+            n_main = np.maximum((share * remaining).astype(np.int64), 1)
+            while n_main.sum() > remaining:
+                n_main[np.argmax(n_main)] -= 1
+            before = query.oracle.calls
+            round_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
+            for j, i in enumerate(sampled_ids):
+                if n_main[j] <= 0:
+                    continue
+                with span("sample", timings, "sample_s", stratum=i):
+                    round_draws[i] = space.sample_stratum(i, int(n_main[j]))
+            round_samples = _label_draws(query, round_draws)
+            for i in sampled_ids:
+                new = round_samples[i]
+                if new is not None:
+                    samples[i] = new if samples[i] is None else samples[i].merge(new)
+            rounds += 1
+            if query.oracle.calls == before:  # everything cached; budget cannot move
+                break
 
     # ---- estimate + CI ----------------------------------------------------
     t0 = time.perf_counter()
     live = [samples[i] for i in range(k + 1) if i not in beta and samples[i] is not None]
     if query.agg in (Agg.COUNT, Agg.SUM, Agg.AVG):
-        est, ci = bootstrap_t_ci(
-            live, blocked, query.agg, query.confidence, cfg.n_bootstrap, rng
-        )
+        with span("bootstrap"):
+            est, ci = bootstrap_t_ci(
+                live, blocked, query.agg, query.confidence, cfg.n_bootstrap, rng
+            )
     elif query.agg in (Agg.MIN, Agg.MAX):
         est = combined_extreme(live, blocked, query.agg.value)
         gb = query.g_bounds
@@ -379,7 +393,8 @@ def run_bas(
     if query.budget >= n_total:
         return run_exact(query)
 
-    space = build_dense_space(query, cfg, rng, timings, weights)
+    with span("stratify"):
+        space = build_dense_space(query, cfg, rng, timings, weights)
     return run_stratified_pipeline(
         query, cfg, rng, space, {"mode": "bas"}, timings, t_start
     )

@@ -47,6 +47,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs.spans import span
+
 from .bas import StratifiedSpace, StratumDraw, run_exact, run_stratified_pipeline
 from .similarity import (
     aligned_pair_weights,
@@ -109,7 +111,8 @@ def build_streaming_space(
     Returns ``(space, extra_detail)`` — the extra detail carries the
     streaming-specific keys (``p_top``, ``use_kernel``) the caller merges
     into its pipeline detail dict.  Shared by ``run_bas_streaming`` and the
-    cascade estimator so both spend stage 1 identically."""
+    cascade estimator so both spend stage 1 identically.  Each D_0 draw is a
+    ``walk_sample`` span, summed into ``timings["walk_s"]``."""
     if use_kernel is None:
         use_kernel = cfg.use_kernel
     if use_sweep is None:
@@ -200,9 +203,10 @@ def build_streaming_space(
 
     def sample_stratum(i: int, n: int) -> StratumDraw:
         if i == 0:
-            tup, pw = _walk_rejection_sample(
-                embeddings, sizes_spec, top_set, n, cfg, rng
-            )
+            with span("walk_sample", timings, "walk_s"):
+                tup, pw = _walk_rejection_sample(
+                    embeddings, sizes_spec, top_set, n, cfg, rng
+                )
             q = pw / max(1.0 - p_top, 1e-12)  # exact prob within D_0
         else:
             pos, q = flat_sample(per_w[i], n, rng, cfg.defensive_mix)
@@ -265,11 +269,12 @@ def run_bas_streaming(
     if query.budget >= query.spec.n_tuples:
         return run_exact(query)
 
-    space, extra = build_streaming_space(
-        query, cfg, rng, timings, n_bins=n_bins, use_kernel=use_kernel,
-        use_sweep=use_sweep, precision=precision, artifact=artifact,
-        index_store=index_store,
-    )
+    with span("stratify"):
+        space, extra = build_streaming_space(
+            query, cfg, rng, timings, n_bins=n_bins, use_kernel=use_kernel,
+            use_sweep=use_sweep, precision=precision, artifact=artifact,
+            index_store=index_store,
+        )
     return run_stratified_pipeline(
         query, cfg, rng, space, {"mode": "bas_streaming", **extra},
         timings, t_start,

@@ -72,6 +72,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.obs.spans import span
+
 from . import allocate as alloc_mod
 from .bas import (
     StratifiedSpace,
@@ -202,7 +204,8 @@ def run_cascade_pipeline(
 ) -> QueryResult:
     """Stages 2-5 of the cascade on an abstract stratified space (dense and
     streaming regimes share this code exactly like plain BAS shares
-    ``run_stratified_pipeline``)."""
+    ``run_stratified_pipeline``).  Each ``sample_stratum`` call is a
+    ``sample`` span, summed into ``timings["sample_s"]``."""
     sizes, weight_sums = space.sizes, space.weight_sums
     k = len(sizes) - 1
     b = query.budget
@@ -215,7 +218,8 @@ def run_cascade_pipeline(
     pilot_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
     for i in range(k + 1):
         if sizes[i] > 0:
-            pilot_draws[i] = space.sample_stratum(i, int(n_pilot[i]))
+            with span("sample", timings, "sample_s", stratum=i):
+                pilot_draws[i] = space.sample_stratum(i, int(n_pilot[i]))
     corr, o_list, p_list = _label_both(query, proxy, pilot_draws)
 
     # linearisation constants (AVG influence function) from the pilot's
@@ -275,7 +279,8 @@ def run_cascade_pipeline(
         n_proxy = _split_budget(n_proxy_total, w_share, floor_n=2)
         proxy_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
         for j, i in enumerate(sampled_ids):
-            proxy_draws[i] = space.sample_stratum(i, int(n_proxy[j]))
+            with span("sample", timings, "sample_s", stratum=i):
+                proxy_draws[i] = space.sample_stratum(i, int(n_proxy[j]))
         proxy_samples = _label_proxy(proxy, query, proxy_draws)
 
     # correction regime: defensive Neyman split on the pilot disagreement
@@ -297,7 +302,8 @@ def run_cascade_pipeline(
         round_draws: list[Optional[StratumDraw]] = [None] * (k + 1)
         for j, i in enumerate(sampled_ids):
             if n_main[j] > 0:
-                round_draws[i] = space.sample_stratum(i, int(n_main[j]))
+                with span("sample", timings, "sample_s", stratum=i):
+                    round_draws[i] = space.sample_stratum(i, int(n_main[j]))
         round_corr, _, _ = _label_both(query, proxy, round_draws)
         for i in sampled_ids:
             new = round_corr[i]
@@ -408,15 +414,17 @@ def run_bas_cascade(
 
     try:
         if path == "dense":
-            space = build_dense_space(query, cfg, rng, timings, weights)
+            with span("stratify"):
+                space = build_dense_space(query, cfg, rng, timings, weights)
             detail = {"mode": "bas-cascade"}
         else:
             from .bas_streaming import build_streaming_space
 
-            space, extra = build_streaming_space(
-                query, cfg, rng, timings, n_bins=n_bins, artifact=artifact,
-                index_store=index_store,
-            )
+            with span("stratify"):
+                space, extra = build_streaming_space(
+                    query, cfg, rng, timings, n_bins=n_bins,
+                    artifact=artifact, index_store=index_store,
+                )
             detail = {"mode": "bas-cascade", **extra}
         return run_cascade_pipeline(
             query, proxy, cfg, rng, space, detail, timings, t_start

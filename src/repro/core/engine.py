@@ -19,6 +19,8 @@ from typing import Callable, Optional, Union
 
 import numpy as np
 
+from repro.obs import spans
+
 from . import baselines, bas, bas_streaming, dispatch
 from .oracle import Oracle
 from .types import Agg, AttrFn, BASConfig, JoinSpec, Query, QueryResult
@@ -202,7 +204,19 @@ class JoinMLEngine:
         """Execute a JoinML query.  ``method="auto"`` (default) routes BAS
         through the memory-aware dispatcher: dense when the flat chain-weight
         array fits under ``cfg.max_dense_weight_bytes``, streaming otherwise.
-        ``"bas"`` / ``"bas-streaming"`` force a path explicitly."""
+        ``"bas"`` / ``"bas-streaming"`` force a path explicitly.
+
+        Each call takes the next process-wide query id
+        (``result.telemetry.query_id``); every span the query opens on this
+        thread carries it (:mod:`repro.obs.spans`)."""
+        qid = spans.next_query_id()
+        with spans.bind(query_id=qid), spans.span("query"):
+            res = self._run(sql, method, seed, budget, confidence)
+        res.telemetry.query_id = qid
+        return res
+
+    def _run(self, sql: str, method: str, seed: int, budget: Optional[int],
+             confidence: Optional[float]) -> QueryResult:
         q = self.build(sql, budget, confidence)
         if method == "auto":
             return dispatch.run_auto(q, self.cfg, seed=seed,

@@ -117,6 +117,17 @@ def make_scorer(cfg, params, tok, left: list, right: list, batch_size: int,
                       max_len=PAIR_LEN, batch_size=batch_size, mesh=mesh)
 
 
+def _scorer_work(scorer, seconds: float) -> str:
+    """The scorer's padding so far (the share of the token slots sent to
+    the device that held no real token: pad columns and pad rows) and the
+    model work its real tokens needed, per second of ``seconds``."""
+    if not scorer.token_slots:
+        return "no tokens scored"
+    pad = 100.0 * (1 - scorer.tokens / scorer.token_slots)
+    rate = scorer.required_flops() / max(seconds, 1e-9) / 1e12
+    return f"{pad:.1f}% padding, {rate:.3f} TFLOP/s of required work"
+
+
 def _run_client(args) -> None:
     """``--mode client``: BAS queries against a remote serving fleet.  Builds
     the same synthetic join the demo server scores (seeded, so every process
@@ -539,7 +550,8 @@ def main():
         labels = sum(o.calls for o in oracles)
         print(f"[serve] {args.queries} concurrent queries, {labels} oracle "
               f"labels in {dt:.2f}s ({labels/max(dt,1e-9):.1f} labels/s, "
-              f"{scorer.forward_batches} device batches)")
+              f"{scorer.forward_batches} device batches, "
+              f"{_scorer_work(scorer, dt)})")
         print(f"[serve] p50={np.quantile(lat, 0.5)*1e3:.0f}ms "
               f"p99={np.quantile(lat, 0.99)*1e3:.0f}ms per query; "
               f"service: {snap['service.windows']:.0f} windows, "
@@ -561,7 +573,8 @@ def main():
         dt = time.time() - t0
         print(f"[serve] scored {len(pairs)} pairs in {dt:.2f}s "
               f"({len(pairs)/max(dt,1e-9):.1f} pairs/s, "
-              f"{scorer.forward_batches} device batches), mean={p.mean():.3f}")
+              f"{scorer.forward_batches} device batches, "
+              f"{_scorer_work(scorer, dt)}), mean={p.mean():.3f}")
 
 
 if __name__ == "__main__":

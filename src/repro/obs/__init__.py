@@ -8,6 +8,8 @@ the typed per-query telemetry tree.
   to ``QueryResult.detail``, with a deprecation-shimmed dict view.
 - :mod:`repro.obs.prometheus` — OpenMetrics text rendering of any
   ``snapshot()`` dict plus a stdlib HTTP ``/metrics`` exporter.
+- :mod:`repro.obs.spans` — host spans on the profiler's clock, tagged with
+  the query or service window they belong to.
 """
 from .prometheus import MetricsExporter, render_openmetrics
 from .telemetry import (

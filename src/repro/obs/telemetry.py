@@ -104,10 +104,12 @@ class QueryTelemetry:
     Sections are ``None`` when the corresponding stage did not run (e.g.
     ``stratify`` on an exact scan, ``index`` without an index store); the
     legacy dict view omits absent sections so ``"stratify" in res.detail``
-    keeps meaning what it always did.
+    keeps meaning what it always did.  ``query_id`` (the id the query's
+    spans carry, see :mod:`repro.obs.spans`) has no legacy key.
     """
 
     mode: str = ""
+    query_id: Optional[int] = None   # span id set by JoinMLEngine.execute
     oracle: Optional[OracleTelemetry] = None
     store: Optional[StoreTelemetry] = None
     stratify: Optional[StratifyTelemetry] = None

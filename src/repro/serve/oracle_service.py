@@ -75,16 +75,21 @@ Architecture
 
 * **Observability + admission control** (``repro.obs``): a pluggable
   :class:`~repro.obs.Tracker` receives window assembly latency, fill/dedup
-  ratios, per-host shard latency, and per-query-class end-to-end flush
-  latency; everything is summarised through one namespaced
-  :meth:`OracleService.snapshot` surface.  Clients attached with a
-  ``deadline_ms`` class are subject to deadline-based admission control:
-  when the measured service rate times the queued backlog implies a
-  deadline miss, their flushes are rejected *before anything is dequeued or
-  charged* with a retryable :class:`AdmissionRejected`.  Worker hosts are
+  ratios, per-host shard latency, per-query-class end-to-end flush
+  latency, how long each flush queued before the dispatcher took it, and
+  the dispatcher's waits on an empty queue; everything is summarised
+  through one namespaced :meth:`OracleService.snapshot` surface.  Clients
+  attached with a ``deadline_ms`` class are subject to deadline-based
+  admission control: when the measured service rate times the queued
+  backlog implies a deadline miss, their flushes are rejected *before
+  anything is dequeued or charged* with a retryable
+  :class:`AdmissionRejected`.  Worker hosts are
   health-checked in the background — a failing host is unregistered (its
   shards fall back to local execution, as in PR 4) and automatically
-  re-registered when its ping answers again.
+  re-registered when its ping answers again.  The dispatcher's spans
+  (``service.starved``, ``service.assemble``, ``service.window`` and what
+  runs inside it, see :mod:`repro.obs.spans`) carry the window's id, and
+  ``service.window`` names the query ids of the flushes it served.
 
 The window/plan/commit machinery here is transport-agnostic, and
 ``repro.serve.transport`` puts a network in front of it: remote client
@@ -99,7 +104,9 @@ docs/serving.md.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
+import itertools
 import threading
 import time
 import weakref
@@ -115,6 +122,7 @@ from repro.core.oracle import (
     plan_requests,
 )
 from repro.obs import NULL_TRACKER, NoopTracker, StreamingHistogram, merge_snapshots
+from repro.obs import spans
 from repro.serve.transport import ThroughputEWMA
 
 
@@ -164,9 +172,12 @@ class _Segment:
     fn: Optional[Callable] = None
     idx: Optional[np.ndarray] = None
     client_id: Optional[int] = None
-    # observability: enqueue time (window assembly latency) + deadline class
+    # observability: enqueue time, the time the dispatcher took the segment
+    # (queue wait vs window assembly), deadline class, the flushing query
     t_enqueue: float = 0.0
+    t_taken: float = 0.0
     qclass: str = "default"
+    query_id: Optional[int] = None
 
     def group_key(self):
         return self.key if self.raw else self.oracle.service_group()
@@ -256,10 +267,11 @@ class OracleService:
         ``label_store.save()``.
     tracker:
         Optional :class:`repro.obs.Tracker` receiving the service's signals
-        (window assembly latency, fill/dedup ratios, per-host shard latency,
-        per-class flush latency, admission/worker events).  Defaults to the
-        noop tracker — the uninstrumented fast path.  Attached stores that
-        have no tracker of their own inherit this one.
+        (window assembly and queue latency, the dispatcher's starved waits,
+        fill/dedup ratios, per-host shard latency, per-class flush latency,
+        admission/worker events).  Defaults to the noop tracker — the
+        uninstrumented fast path.  Attached stores that have no tracker of
+        their own inherit this one.
     health_check_s:
         Period of the background worker-host health checker (started with
         the first :meth:`register_remote_worker`).  A host that fails a
@@ -387,6 +399,9 @@ class OracleService:
                     self._classes[o] = query_class or f"dl{int(deadline_ms)}"
                 elif query_class is not None:
                     self._classes[o] = query_class
+            # a waiting dispatcher counts starved time only while a client
+            # is attached: it has to see the set change
+            self._cv.notify_all()
         return self
 
     def detach(self, *oracles: Oracle) -> None:
@@ -450,6 +465,7 @@ class OracleService:
                 batch=batch, oracle=batch.oracle, requests=requests,
                 future=Future(), rows=rows,
                 t_enqueue=time.monotonic(), qclass=qclass,
+                query_id=spans.current_ids().get("query_id"),
             )
             self._queue.append(seg)
             self._queued_rows += rows
@@ -483,6 +499,7 @@ class OracleService:
             self._client_seq += 1
             cid = self._client_seq
             self._remote_clients.add(cid)
+            self._cv.notify_all()                # see attach
             return cid
 
     def client_disconnected(self, client_id: int) -> None:
@@ -730,18 +747,50 @@ class OracleService:
     # ---- dispatcher --------------------------------------------------------
 
     def _run(self) -> None:
-        while True:
-            with self._cv:
-                while not self._queue and not self._closed:
-                    self._cv.wait()
-                if not self._queue:
+        for window_id in itertools.count(1):
+            with spans.bind(window_id=window_id):
+                window = self._assemble()
+                if window is None:
                     return                       # closed and drained
-                window = [self._queue.pop(0)]
+                self._dispatch(window)
+
+    def _take(self) -> _Segment:
+        seg = self._queue.pop(0)
+        seg.t_taken = time.monotonic()
+        return seg
+
+    def _assemble(self) -> Optional[list[_Segment]]:
+        """Wait for a first flush, then gather the window: flushes until
+        ``max_batch`` rows, the deadline, or every attached client is
+        present.  None once closed and drained.
+
+        The wait on an empty queue is starved while a client is attached (a
+        query is running and has no flush in): time the service could have
+        scored but was given nothing.  With no client attached it is idle,
+        and not counted."""
+        starved = 0.0
+        with self._cv:
+            if not self._queue and not self._closed:
+                t = time.monotonic()
+                with spans.span("service.starved"):
+                    while not self._queue and not self._closed:
+                        # attach, detach and the transport's (dis)connects
+                        # change the client sets under this lock and notify
+                        clients = bool(self._clients or self._remote_clients)
+                        self._cv.wait()
+                        now = time.monotonic()
+                        if clients:
+                            starved += now - t
+                        t = now
+            if not self._queue:
+                return None
+            with spans.span("service.assemble"):
+                window = [self._take()]
                 rows = window[0].rows
                 deadline = time.monotonic() + self.max_wait_s
                 while rows < self.max_batch:
                     if self._queue:
-                        seg = self._queue.pop(0)
+                        seg = self._take()
                         window.append(seg)
                         rows += seg.rows
                         continue
@@ -764,49 +813,63 @@ class OracleService:
                 # queue behind it (admission control's backlog view)
                 self._queued_rows -= rows
                 self._inflight_rows = rows
-            if self._tracking:
-                t_dispatch = time.monotonic()
-                for seg in window:
-                    self.tracker.observe(
-                        "service.window.assembly_ms",
-                        (t_dispatch - seg.t_enqueue) * 1e3,
-                    )
-            t_proc = time.perf_counter()
-            try:
+        if self._tracking:
+            t_dispatch = time.monotonic()
+            if starved > 0.0:
+                self.tracker.observe("service.dispatcher.starved_ms",
+                                     starved * 1e3)
+            for seg in window:
+                self.tracker.observe(
+                    "service.window.assembly_ms",
+                    (t_dispatch - seg.t_enqueue) * 1e3,
+                )
+                self.tracker.observe(
+                    "service.window.queue_ms",
+                    (seg.t_taken - seg.t_enqueue) * 1e3,
+                )
+        return window
+
+    def _dispatch(self, window: list[_Segment]) -> None:
+        rows = sum(seg.rows for seg in window)
+        query_ids = " ".join(str(seg.query_id) for seg in window
+                             if seg.query_id is not None)
+        t_proc = time.perf_counter()
+        try:
+            with spans.span("service.window", rows=rows, query_ids=query_ids):
                 self._process(window)
-            except BaseException as e:  # noqa: BLE001 — dispatcher must survive
-                for seg in window:
-                    if not seg.future.done():
-                        seg.fail(e)
-            finally:
-                elapsed = time.perf_counter() - t_proc
-                with self._cv:
-                    self._inflight_rows = 0
-                    if rows and elapsed > 0:
-                        # EWMA of the measured service rate (rows/s) feeding
-                        # admission control's predicted-wait estimate; the
-                        # sample also updates every deadline class present in
-                        # this window so each class predicts from its own
-                        # history only
-                        sample = rows / elapsed
-                        self._service_rate = (
-                            sample if self._service_rate <= 0.0
-                            else 0.7 * self._service_rate + 0.3 * sample
+        except BaseException as e:  # noqa: BLE001 — dispatcher must survive
+            for seg in window:
+                if not seg.future.done():
+                    seg.fail(e)
+        finally:
+            elapsed = time.perf_counter() - t_proc
+            with self._cv:
+                self._inflight_rows = 0
+                if rows and elapsed > 0:
+                    # EWMA of the measured service rate (rows/s) feeding
+                    # admission control's predicted-wait estimate; the
+                    # sample also updates every deadline class present in
+                    # this window so each class predicts from its own
+                    # history only
+                    sample = rows / elapsed
+                    self._service_rate = (
+                        sample if self._service_rate <= 0.0
+                        else 0.7 * self._service_rate + 0.3 * sample
+                    )
+                    for qc in {seg.qclass for seg in window}:
+                        prev = self._class_rates.get(qc, 0.0)
+                        self._class_rates[qc] = (
+                            sample if prev <= 0.0
+                            else 0.7 * prev + 0.3 * sample
                         )
-                        for qc in {seg.qclass for seg in window}:
-                            prev = self._class_rates.get(qc, 0.0)
-                            self._class_rates[qc] = (
-                                sample if prev <= 0.0
-                                else 0.7 * prev + 0.3 * sample
-                            )
-            # pools retired by register_remote_worker are quiescent once the
-            # window completes (this thread is their only submitter and
-            # _execute awaits every shard), so their threads are reaped here
-            # instead of leaking until close()
-            with self._lock:
-                retired, self._retired_pools = self._retired_pools, []
-            for pool in retired:
-                pool.shutdown(wait=True)
+        # pools retired by register_remote_worker are quiescent once the
+        # window completes (this thread is their only submitter and
+        # _execute awaits every shard), so their threads are reaped here
+        # instead of leaking until close()
+        with self._lock:
+            retired, self._retired_pools = self._retired_pools, []
+        for pool in retired:
+            pool.shutdown(wait=True)
 
     # ---- window processing -------------------------------------------------
 
@@ -816,7 +879,8 @@ class OracleService:
         rows_w = sum(seg.rows for seg in window)
         self.window_rows += rows_w
         planned_before = self.rows_planned
-        plans = self._plan(window)
+        with spans.span("service.plan"):
+            plans = self._plan(window)
         # per-window fill/dedup observations: the *_recent snapshot keys and
         # (when a tracker is attached) the service.window.{fill,dedup} series
         fill = rows_w / self.max_batch
@@ -832,10 +896,11 @@ class OracleService:
                 groups.setdefault(plan.seg.group_key(), []).append(plan)
             for key, group in groups.items():
                 self._execute_group(key, group)
-            for plan in plans:                   # commit in arrival order
-                if plan.seg.future.done():       # its group failed
-                    continue
-                self._commit(plan)
+            with spans.span("service.commit"):
+                for plan in plans:               # commit in arrival order
+                    if plan.seg.future.done():   # its group failed
+                        continue
+                    self._commit(plan)
         except BaseException as e:
             # a dispatcher-level failure must not leave store reservations
             # dangling — waiters (possibly in another service sharing the
@@ -1023,11 +1088,15 @@ class OracleService:
         labels += ["local"] * (n_shards - n_remote)
         shards = self._capacity_split(idx, labels)
         self.backend_calls += n_shards
+        # each shard runs in a copy of this thread's context, so its spans
+        # carry the window's id
         futs = [
-            self._pool.submit(self._execute_remote, w, key[1], fn, s)
+            self._pool.submit(contextvars.copy_context().run,
+                              self._execute_remote, w, key[1], fn, s)
             for w, s in zip(remotes, shards[:n_remote])
         ]
-        futs += [self._pool.submit(self._execute_local, fn, s)
+        futs += [self._pool.submit(contextvars.copy_context().run,
+                                   self._execute_local, fn, s)
                  for s in shards[n_remote:]]
         return np.concatenate(
             [np.asarray(f.result(), np.float64) for f in futs]

@@ -18,6 +18,7 @@ standard serving pattern for mixed-length batches.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Callable
 
 import jax
@@ -27,6 +28,7 @@ import numpy as np
 from repro.launch.sharding import data_parallel, mesh_batch_shards
 from repro.models import decode_step, forward, init_cache
 from repro.models.config import ModelConfig
+from repro.obs.spans import span
 
 
 def _stable_yes_no_prob(lg: np.ndarray) -> np.ndarray:
@@ -37,6 +39,22 @@ def _stable_yes_no_prob(lg: np.ndarray) -> np.ndarray:
     return e[:, 0] / (e[:, 0] + e[:, 1])
 
 
+def required_flops(cfg: ModelConfig, tokens: int, causal_pairs: int,
+                   pairs: int) -> float:
+    """FLOPs that scoring ``pairs`` pairs of ``tokens`` real tokens in all
+    needs, padding excluded: 2 x the active parameters outside the
+    embeddings and norms per token, 4 x ``num_heads x head_dim`` per causal
+    (query, key) pair in each attention layer, and the 2-column yes/no head
+    per pair (not the full-vocabulary head ``forward`` computes)."""
+    d = cfg.d_model
+    embed = cfg.vocab_size * d * (1 if cfg.tied_embeddings else 2)
+    matmul = cfg.active_param_count() - embed - 2 * d * cfg.num_layers
+    attn_layers = sum(t in ("dense", "moe", "attn") for t in cfg.layer_types())
+    return (2.0 * matmul * tokens
+            + 4.0 * attn_layers * cfg.num_heads * cfg.head_dim * causal_pairs
+            + 4.0 * d * pairs)
+
+
 class PairScorer:
     """Batched Oracle scoring: score(idx_pairs) -> P(match) per pair.
 
@@ -44,7 +62,12 @@ class PairScorer:
     of the jitted forward is sharded over the mesh's batch axes (SERVE_RULES)
     via ``shard_map``; ``batch_size`` is rounded up to a multiple of the
     shard count.  ``forward_batches`` counts compiled-forward invocations —
-    the unit the ISSUE's ceil(unique/batch_size) bound is stated in.
+    the unit the ceil(unique/batch_size) bound is stated in.  Per padded
+    block ``score`` also counts ``token_slots`` (``pad_len x batch_size``),
+    ``tokens`` (real tokens) and ``causal_pairs`` (sum of L(L+1)/2 over the
+    real rows), so ``1 - tokens / token_slots`` is the padding share and
+    :meth:`required_flops` the model work done.  Its spans: ``tokenize``, then per block ``scorer.pad``, ``scorer.forward``
+    and ``scorer.fetch`` (waiting for the device's logits).
     """
 
     def __init__(self, cfg: ModelConfig, params, tokenize_pair: Callable,
@@ -58,6 +81,10 @@ class PairScorer:
         self.mesh = mesh
         self.forward_batches = 0   # compiled forward invocations
         self.pairs_scored = 0
+        self.token_slots = 0
+        self.tokens = 0
+        self.causal_pairs = 0
+        self._count_lock = threading.Lock()   # score may run on several threads
 
         def fwd(p, b):
             # [yes, no] logits at each row's last real position, gathered on
@@ -94,6 +121,12 @@ class PairScorer:
         real position)."""
         return self._pad_block(self._tokenize(np.asarray(pairs)), pad_len)
 
+    def required_flops(self) -> float:
+        """FLOPs the pairs scored so far needed (:func:`required_flops`)."""
+        with self._count_lock:
+            return required_flops(self.cfg, self.tokens, self.causal_pairs,
+                                  self.pairs_scored)
+
     def _tokenize(self, pairs: np.ndarray) -> list:
         return [
             np.asarray(self.tokenize_pair(p), np.int32)[: self.max_len]
@@ -119,25 +152,37 @@ class PairScorer:
         n = len(pairs)
         if n == 0:
             return np.zeros(0, np.float64)
-        seqs = self._tokenize(pairs)
+        with span("tokenize"):
+            seqs = self._tokenize(pairs)
         lens = np.fromiter((len(s) for s in seqs), np.int64, n)
         pad_of = self._buckets[np.searchsorted(self._buckets, lens)]
         out = np.empty(n, np.float64)
         bs = self.batch_size
+        slots = 0
         for pad_len in np.unique(pad_of):
             sel = np.nonzero(pad_of == pad_len)[0]
             for s in range(0, len(sel), bs):
                 idxs = sel[s : s + bs]
-                toks, last = self._pad_block([seqs[i] for i in idxs], int(pad_len))
-                pad_rows = bs - len(idxs)
-                if pad_rows:
-                    toks = np.concatenate(
-                        [toks, np.zeros((pad_rows, int(pad_len)), np.int32)]
-                    )
-                    last = np.concatenate([last, np.zeros(pad_rows, np.int32)])
-                lg = np.asarray(self.yes_no_logits(toks, last), np.float64)
+                with span("scorer.pad"):
+                    toks, last = self._pad_block([seqs[i] for i in idxs],
+                                                 int(pad_len))
+                    pad_rows = bs - len(idxs)
+                    if pad_rows:
+                        toks = np.concatenate(
+                            [toks, np.zeros((pad_rows, int(pad_len)), np.int32)]
+                        )
+                        last = np.concatenate([last, np.zeros(pad_rows, np.int32)])
+                slots += int(pad_len) * bs
+                with span("scorer.forward"):
+                    dev = self.yes_no_logits(toks, last)
+                with span("scorer.fetch"):
+                    lg = np.asarray(dev, np.float64)
                 out[idxs] = _stable_yes_no_prob(lg)[: len(idxs)]
-        self.pairs_scored += n
+        with self._count_lock:
+            self.pairs_scored += n
+            self.token_slots += slots
+            self.tokens += int(lens.sum())
+            self.causal_pairs += int((lens * (lens + 1) // 2).sum())
         return out
 
 
