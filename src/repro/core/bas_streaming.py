@@ -70,11 +70,13 @@ def _walk_rejection_sample(
     n: int,
     cfg: BASConfig,
     rng: np.random.Generator,
+    timings: Optional[dict] = None,
     max_rounds: int = 50,
 ):
     """Sample n tuples from D_0 with exact probabilities: k-way WWJ walk
     proposals, rejected when they land in the blocking regime.  Returns
-    ((m, k) tuples, (m,) full-space walk probabilities), m <= n."""
+    ((m, k) tuples, (m,) full-space walk probabilities), m <= n.  The
+    walks' spans and counters go to ``timings`` (:func:`walk_sample`)."""
     k = len(embeddings)
     out_idx = np.empty((n, k), np.int64)
     out_p = np.empty(n, np.float64)
@@ -84,7 +86,8 @@ def _walk_rejection_sample(
         if need <= 0:
             break
         m = max(int(need * 1.3) + 16, 32)
-        ws = walk_sample(embeddings, m, rng, cfg.weight_exponent, cfg.weight_floor)
+        ws = walk_sample(embeddings, m, rng, cfg.weight_exponent,
+                         cfg.weight_floor, timings=timings)
         flat = tuples_to_flat(ws.idx, sizes)
         keep = np.fromiter((f not in top_set for f in flat), bool, len(flat))
         take = min(int(keep.sum()), need)
@@ -205,7 +208,7 @@ def build_streaming_space(
         if i == 0:
             with span("walk_sample", timings, "walk_s"):
                 tup, pw = _walk_rejection_sample(
-                    embeddings, sizes_spec, top_set, n, cfg, rng
+                    embeddings, sizes_spec, top_set, n, cfg, rng, timings
                 )
             q = pw / max(1.0 - p_top, 1e-12)  # exact prob within D_0
         else:

@@ -17,14 +17,21 @@ Two samplers:
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .similarity import pair_weights
+from repro.obs.spans import span
+
+from .similarity import _pair_weights_jax, weight_of_score
 from .types import ConfidenceInterval
 
 
-_CHUNK_ELEMS = 1 << 25  # 256 MiB of f64 walk-step weights per chunk
+BLOCK = 128  # columns per block of a walk step's two-level draw
+DRAW_ROWS = 32  # walks per host draw: bounds its f64 copies of the blocks
 
 
 @dataclasses.dataclass
@@ -33,15 +40,51 @@ class WalkSample:
     prob: np.ndarray   # (n,) sampling probability of each tuple (exact)
 
 
-def _categorical_rows(w: np.ndarray, rng: np.random.Generator) -> tuple[np.ndarray, np.ndarray]:
-    """Per-row categorical sample.  Returns (choice, prob_of_choice)."""
-    totals = w.sum(axis=1, keepdims=True)
-    cdf = np.cumsum(w, axis=1) / totals
-    u = rng.random((w.shape[0], 1))
-    choice = (cdf < u).sum(axis=1)
-    choice = np.minimum(choice, w.shape[1] - 1)
-    prob = np.take_along_axis(w, choice[:, None], axis=1)[:, 0] / totals[:, 0]
-    return choice.astype(np.int64), prob
+@functools.partial(jax.jit, static_argnames=("exponent", "floor"))
+def _block_sums(cur, table, exponent: float, floor: float):
+    """(rows, ceil(N / BLOCK)) f32 sums of each row's weights against
+    ``table`` over blocks of ``BLOCK`` columns; the padding weighs 0."""
+    w = _pair_weights_jax(cur, table, exponent, floor)
+    w = jnp.pad(w, ((0, 0), (0, -table.shape[0] % BLOCK)))
+    return w.reshape(w.shape[0], -1, BLOCK).sum(axis=2)
+
+
+def _draw(u: np.ndarray, w: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Per row of ``w`` (f64, >= 0), the position whose cumulative weight
+    first exceeds ``u`` times the row's total, and its share of that total.
+    A position of weight 0 is never drawn."""
+    cdf = np.cumsum(w, axis=1)
+    tot = cdf[:, -1]
+    pos = (cdf <= (u * tot)[:, None]).sum(axis=1)
+    last = w.shape[1] - 1 - np.argmax(w[:, ::-1] > 0, axis=1)
+    pos = np.minimum(pos, last)   # u * tot rounded up to tot
+    return pos, np.take_along_axis(w, pos[:, None], axis=1)[:, 0] / tot
+
+
+def _draw_in_blocks(sums: np.ndarray, cur: np.ndarray, table: np.ndarray,
+                    u: np.ndarray, exponent: float,
+                    floor: float) -> tuple[np.ndarray, np.ndarray]:
+    """One walk step on the host: per walk (a row of ``cur``), a block b in
+    proportion to its f32 sum S_b (``sums``), then a record of it in
+    proportion to its weight recomputed in f64.  Returns (records, the
+    probability (S_b / sum S) (w_j / sum_b w) of each draw); ``u`` holds
+    the two uniforms of each walk."""
+    n2 = table.shape[0]
+    out = np.empty(len(cur), np.int64)
+    prob = np.empty(len(cur), np.float64)
+    for s in range(0, len(cur), DRAW_ROWS):
+        sl = slice(s, s + DRAW_ROWS)
+        b, p_block = _draw(u[sl, 0], sums[sl].astype(np.float64))
+        cols = b[:, None] * BLOCK + np.arange(BLOCK)
+        real = cols < n2
+        cols = np.minimum(cols, n2 - 1)
+        sims = np.einsum("nkd,nd->nk", table[cols].astype(np.float64),
+                         cur[sl].astype(np.float64))
+        w = np.where(real, weight_of_score(sims, exponent, floor), 0.0)
+        j, p_rec = _draw(u[sl, 1], w)
+        out[sl] = cols[np.arange(len(j)), j]
+        prob[sl] = p_block * p_rec
+    return out, prob
 
 
 def walk_sample(
@@ -50,28 +93,58 @@ def walk_sample(
     rng: np.random.Generator,
     exponent: float = 1.0,
     floor: float = 1e-3,
-    chunk: int = 4096,
+    chunk: int = 512,
+    timings: Optional[dict] = None,
 ) -> WalkSample:
-    """n independent WWJ random walks over a k-table chain.  Each step
-    materialises (rows, N_next) f64 weights for up to ``chunk`` walks at a
-    time, fewer where N_next is wide, so a chunk holds at most
-    ``_CHUNK_ELEMS`` weights and concurrent queries stay within host
-    memory.  The draws do not depend on the chunking."""
+    """n independent WWJ random walks over a k-table chain.
+
+    Each step draws the next record of a walk in proportion to its weight,
+    in two levels.  The device computes the walks' weights against the next
+    table (uploaded once per edge) in launches of ``chunk`` rows, one
+    program per table shape, and returns only their f32 sums over blocks of
+    ``BLOCK`` columns; the host then draws a block and a record of it
+    (:func:`_draw_in_blocks`).  Every real record weighs at least ``floor``
+    in both levels, so every tuple stays reachable.  Two uniforms per walk
+    and step are drawn up front, so the draws do not depend on ``chunk``.
+
+    With ``timings``, the spans ``walk.blocks`` (upload, device steps and
+    fetches) and ``walk.draw`` (host draws) add their seconds to
+    ``walk_blocks_s`` and ``walk_draw_s``; ``walk_launches`` and
+    ``walk_fetch_bytes`` count the device steps and the bytes fetched."""
     k = len(embeddings)
     n1 = embeddings[0].shape[0]
     idx = np.empty((n, k), np.int64)
     prob = np.full((n,), 1.0 / n1, np.float64)
     idx[:, 0] = rng.integers(0, n1, size=n)
+    launches = fetched = 0
     for step in range(k - 1):
-        rows = max(1, min(chunk, _CHUNK_ELEMS // embeddings[step + 1].shape[0]))
-        for s in range(0, n, rows):
-            cur = idx[s : s + rows, step]
-            w = pair_weights(
-                embeddings[step][cur], embeddings[step + 1], exponent, floor
-            )
-            nxt, p = _categorical_rows(w, rng)
-            idx[s : s + rows, step + 1] = nxt
+        prev = np.asarray(embeddings[step], np.float32)
+        nxt = np.asarray(embeddings[step + 1], np.float32)
+        u = rng.random((n, 2))
+        # every launch of the edge is queued before the first fetch
+        pending = []
+        with span("walk.blocks", timings, "walk_blocks_s"):
+            table = jax.device_put(nxt)
+            for s in range(0, n, chunk):
+                rows = min(chunk, n - s)
+                cur = np.zeros((chunk, nxt.shape[1]), np.float32)
+                cur[:rows] = prev[idx[s : s + rows, step]]
+                pending.append((s, rows, cur,
+                                _block_sums(cur, table, exponent, floor)))
+        for s, rows, cur, out in pending:
+            with span("walk.blocks", timings, "walk_blocks_s"):
+                sums = np.asarray(out)
+            launches += 1
+            fetched += sums.nbytes
+            with span("walk.draw", timings, "walk_draw_s"):
+                j, p = _draw_in_blocks(sums[:rows], cur[:rows], nxt,
+                                       u[s : s + rows], exponent, floor)
+            idx[s : s + rows, step + 1] = j
             prob[s : s + rows] *= p
+    if timings is not None:
+        timings["walk_launches"] = timings.get("walk_launches", 0) + launches
+        timings["walk_fetch_bytes"] = (timings.get("walk_fetch_bytes", 0)
+                                       + fetched)
     return WalkSample(idx=idx, prob=prob)
 
 

@@ -27,10 +27,12 @@ mirrors this list):
   (``build_dense_space`` / ``build_streaming_space``), ``pilot``,
   ``allocate``, ``execute`` (the pipeline's stages), ``sample`` (each
   ``sample_stratum`` call; ``timings["sample_s"]``), ``walk_sample`` (the D0
-  walk+rejection sampler inside ``sample``; ``walk_s``), ``oracle.wait``
-  (blocked on the oracle's future; ``oracle_wait_s``), ``bootstrap``; the
-  cascade path opens ``query``, ``stratify``, ``sample`` and
-  ``walk_sample`` of these;
+  walk+rejection sampler inside ``sample``; ``walk_s``) holding
+  ``walk.blocks`` (the walk steps' device work and fetches;
+  ``walk_blocks_s``) and ``walk.draw`` (their host draws; ``walk_draw_s``),
+  ``oracle.wait`` (blocked on the oracle's future; ``oracle_wait_s``),
+  ``bootstrap``; the cascade path opens ``query``, ``stratify``, ``sample``
+  and ``walk_sample`` (with its two) of these;
 - the oracle service's dispatcher: ``service.starved`` (waiting on an empty
   queue: starved while a client is attached, else idle),
   ``service.assemble`` (first flush taken to dispatch), ``service.window``
@@ -49,7 +51,8 @@ from typing import Optional
 from jax.profiler import TraceAnnotation
 
 QUERY_SPANS = ("query", "stratify", "pilot", "allocate", "execute", "sample",
-               "walk_sample", "oracle.wait", "bootstrap")
+               "walk_sample", "walk.blocks", "walk.draw", "oracle.wait",
+               "bootstrap")
 DISPATCHER_SPANS = ("service.starved", "service.assemble", "service.window",
                     "service.plan", "tokenize", "scorer.pad",
                     "scorer.forward", "scorer.fetch", "service.commit")

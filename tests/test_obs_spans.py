@@ -160,10 +160,11 @@ def test_spans_on_their_threads_with_ids(traced, dense):
     assert len(query_lines) == len(results)   # one analyst thread each
     assert len(dispatcher_lines) == 1
     seen = {n for _, n, _ in events}
-    want = set(spans.SPANS) - ({"walk_sample"} if dense else set())
+    walk = {"walk_sample", "walk.blocks", "walk.draw"}
+    want = set(spans.SPANS) - (walk if dense else set())
     assert want <= seen, want - seen
     if dense:
-        assert "walk_sample" not in seen
+        assert not walk & seen
     qids = {r.telemetry.query_id for r in results}
     assert len(qids) == len(results) and None not in qids
     for key, name, stats in events:
@@ -205,6 +206,8 @@ def test_timings_split_sampling_and_oracle_wait(traced, dense):
         assert ("walk_s" in t) != dense
         if not dense:
             assert 0 < t["walk_s"] <= t["sample_s"]
+            assert t["walk_blocks_s"] + t["walk_draw_s"] <= t["walk_s"]
+            assert t["walk_launches"] >= 1 and t["walk_fetch_bytes"] > 0
         assert t["sample_s"] + t["oracle_wait_s"] <= (
             t["pilot_s"] + t["execute_s"] + 1e-6)
 
