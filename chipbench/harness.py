@@ -350,16 +350,22 @@ def instrument(probe: Probe, scorer) -> None:
 # ---------------------------------------------------------------------------
 
 def oracle_config(oracle: dict):
-    """The program's model configuration with the file's sizes."""
+    """The program's model configuration with the file's sizes, of a family
+    that the file names as ``repro.configs`` registers it for the ``arch``,
+    and that has a reference (``chipbench/oracles/<family>.py``) that can
+    represent it."""
+    from chipbench import oracles
     from repro.configs import get_config
 
     base = get_config(oracle["arch"])
+    if oracle.get("family") != base.family:
+        raise ValueError(f"{oracle['arch']} is of family {base.family!r}; "
+                         f"the oracle section says {oracle.get('family')!r}")
+    family = oracles.load(base.family)
     fields = {f.name for f in dataclasses.fields(base)}
     cfg = dataclasses.replace(base, **{k: v for k, v in oracle.items()
                                        if k in fields and k != "name"})
-    if cfg.family != "dense" or not cfg.tied_embeddings or cfg.act != "silu":
-        raise ValueError(f"{cfg.name}: the reference knows a dense, tied, "
-                         "SwiGLU decoder")
+    family.check(cfg)
     return cfg
 
 
@@ -377,6 +383,19 @@ def build_scorer(config: dict, cfg, params, left: list, right: list):
 
     return PairScorer(cfg, params, tokenize_pair, tok.YES, tok.NO,
                       max_len=max_len, batch_size=int(o["batch_size"]))
+
+
+SCORER_COUNTERS = ("tokens", "token_slots", "causal_pairs", "pairs_scored")
+
+
+def scorer_counters(scorer) -> dict:
+    """The scorer's counters by name: ``scorer.counters()`` where the
+    program offers it (a family's own counters with the common ones), else
+    the common ones read one by one."""
+    own = getattr(scorer, "counters", None)
+    if own is not None:
+        return dict(own())
+    return {k: getattr(scorer, k) for k in SCORER_COUNTERS}
 
 
 def used_buckets(scorer, left: list, right: list) -> list:
@@ -544,6 +563,8 @@ class Context:
     sweeps: list           # shapes of each sweep in the window
     tracker: object = None
     trace: object = None   # trace.Summary of the traced window
+    # what the scorer's counters (``scorer_counters``) rose by in the window
+    scorer_counters: dict = dataclasses.field(default_factory=dict)
 
 
 def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
@@ -603,8 +624,11 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
             opts.python_tracer_level = 0
             opts.host_tracer_level = 2
             jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        before = scorer_counters(scorer)
         queries, labels, window_s, failures = _window(
             system, analysts, probe, seconds, float(traffic.get("stagger_s", 0)))
+        counted = {k: v - before[k]
+                   for k, v in scorer_counters(scorer).items()}
         if traced:
             jax.profiler.stop_trace()
     finally:
@@ -633,7 +657,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t_start: float,
                      scorer, failures, (e1, e2), ctl)
     correct = all(v <= lim for v, lim in checks.values())
     ctx = Context(config, cell.chips, peaks, setup_s, window_s, queries, labels,
-                  probe.blocks, probe.sweeps, tracker, summary)
+                  probe.blocks, probe.sweeps, tracker, summary, counted)
     metrics = {}
     for m in cell.per_layer if traced else cell.end_to_end:
         v = read_metric(m["name"], ctx)

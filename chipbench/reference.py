@@ -4,10 +4,11 @@ Nothing here imports the program.  The references follow the published
 semantics: the sweep's weight transform max(clip(e1 . e2, 0, 1), floor) **
 exponent, binned, ranked and summed in float64 on the host; the sampling
 weight of each drawn pair under the same transform; COUNT's
-Horvitz-Thompson estimate and its bootstrap-t CI in float64; and a dense,
-tied-embedding SwiGLU decoder with RoPE, in float32 at the highest matmul
-precision.  Each takes a ``control`` flag (or ``dtype``) that computes the
-same thing one precision below what the configuration states (the products
+Horvitz-Thompson estimate and its bootstrap-t CI in float64; and the
+oracle's forward in float32 at the highest matmul precision, by the module
+of its family under ``chipbench/oracles/``.  Each takes a ``control`` flag
+(or ``dtype``) that computes the same thing one precision below what the
+configuration states (the products
 at ``Precision.HIGH``, three bf16 passes, instead of float32 at
 ``HIGHEST``; the estimate in float32 instead of float64; the oracle's
 matmuls in float8 e4m3 instead of bfloat16): the control must fail the
@@ -15,11 +16,11 @@ comparison that the program passes.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import oracles
 
 
 # ---------------------------------------------------------------------------
@@ -232,79 +233,12 @@ def ht_count_ci(strata, blocked, p: float, n_boot: int, rng_state: dict,
 # the oracle's yes / no logits
 # ---------------------------------------------------------------------------
 
-def _fp8(a):
-    """Round to float8 e4m3 with a per-tensor scale, back in float32."""
-    s = jnp.maximum(jnp.max(jnp.abs(a)), 1e-30) / 448.0
-    return (a / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("heads", "kv_heads", "eps", "control"))
-def _layer(x, p, cos, sin, mask, heads, kv_heads, eps, control):
-    hp = jax.lax.Precision.HIGHEST
-    q8 = _fp8 if control else (lambda a: a)
-
-    def mm(a, b):
-        return jnp.matmul(q8(a), q8(b), precision=hp)
-
-    def norm(v, w):
-        v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
-        return v * (1.0 + w)
-
-    def rope(t):
-        t1, t2 = jnp.split(t, 2, axis=-1)
-        return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin], -1)
-
-    b, s, _ = x.shape
-    hd = cos.shape[-1] * 2
-    a = norm(x, p["ln1"])
-    q = rope(mm(a, p["attn"]["wq"]).reshape(b, s, heads, hd))
-    k = rope(mm(a, p["attn"]["wk"]).reshape(b, s, kv_heads, hd))
-    v = mm(a, p["attn"]["wv"]).reshape(b, s, kv_heads, hd)
-    k, v = (jnp.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q8(q), q8(k), precision=hp) * hd**-0.5
-    att = jax.nn.softmax(jnp.where(mask, sc, -jnp.inf), axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", q8(att), q8(v),
-                   precision=hp).reshape(b, s, heads * hd)
-    x = x + mm(o, p["attn"]["wo"])
-    m = norm(x, p["ln2"])
-    return x + mm(jax.nn.silu(mm(m, p["mlp"]["w_gate"])) * mm(m, p["mlp"]["w_up"]),
-                  p["mlp"]["w_down"])
-
-
 def yes_no_logits(oracle: dict, params, toks, last, yes: int, no: int,
                   control: bool = False) -> np.ndarray:
-    """(B, 2) float64 [yes, no] logits at each row's ``last`` position of a
-    dense, tied-embedding, SwiGLU decoder with RoPE (``oracle`` is the
-    configuration's oracle section), computed layer by layer in float32."""
-    want = {"embed", "ln_f", "layers"}
-    if set(params) != want or set(params["layers"]) != {"ln1", "ln2", "attn", "mlp"}:
-        raise ValueError(f"oracle weights hold {sorted(params)}; the "
-                         "reference knows a dense tied-embedding decoder")
-    f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-    toks = np.asarray(toks)
-    b, s = toks.shape
-    hd = oracle["head_dim"]
-    pos = np.arange(s)
-    inv = 1.0 / oracle["rope_theta"] ** (np.arange(0, hd, 2) / hd)
-    cos = f32(np.cos(pos[:, None] * inv))[None, :, None, :]
-    sin = f32(np.sin(pos[:, None] * inv))[None, :, None, :]
-    mask = jnp.asarray(pos[:, None] >= pos[None, :])
-    eps = float(oracle["norm_eps"])
-    embed = f32(params["embed"])
-    x = embed[jnp.asarray(toks)]
-    for layer in range(oracle["num_layers"]):
-        p = jax.tree.map(lambda a: f32(a[layer]), params["layers"])
-        x = _layer(x, p, cos, sin, mask, heads=oracle["num_heads"],
-                   kv_heads=oracle["num_kv_heads"], eps=eps, control=control)
-    h = x[jnp.arange(b), jnp.asarray(last)]
-    h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + eps)
-    h = h * (1.0 + f32(params["ln_f"]))
-    head = embed[jnp.asarray([yes, no])]
-    if control:
-        h, head = _fp8(h), _fp8(head)
-    lg = jnp.matmul(h, head.T, precision=jax.lax.Precision.HIGHEST)
-    return np.asarray(lg, np.float64)
+    """(B, 2) float64 [yes, no] logits at each row's ``last`` position, by
+    the reference of the oracle's family (``chipbench/oracles/``)."""
+    return oracles.load(oracle["family"]).yes_no_logits(
+        oracle, params, toks, last, yes, no, control=control)
 
 
 def logit_dev(got: np.ndarray, want: np.ndarray) -> float:

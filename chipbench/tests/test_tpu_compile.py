@@ -17,7 +17,8 @@ from jax.sharding import SingleDeviceSharding
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench import harness, reference  # noqa: E402
+from chipbench import harness  # noqa: E402
+from chipbench.oracles import dense  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -85,5 +86,5 @@ def test_reference_layer_compiles_for_v5e(one_chip):
             _spec((1, s, 1, hd // 2), f32, one_chip),
             _spec((s, s), jnp.bool_, one_chip))
     for control in (False, True):
-        reference._layer.lower(*args, heads=h, kv_heads=o["num_kv_heads"],
-                               eps=o["norm_eps"], control=control).compile()
+        dense._layer.lower(*args, heads=h, kv_heads=o["num_kv_heads"],
+                           eps=o["norm_eps"], control=control).compile()
