@@ -1,4 +1,4 @@
-"""Architecture config registry: the 10 assigned architectures + the paper's
+"""Architecture config registry: the assigned architectures + the paper's
 own small Oracle/embedder models.  ``get_config(name)`` returns the full
 config; ``get_smoke_config(name)`` the reduced CPU-testable variant.
 """
@@ -19,6 +19,7 @@ ARCHS = [
     "rwkv6-1.6b",
     "pixtral-12b",
     "recurrentgemma-9b",
+    "deepseek-v2-lite",
 ]
 
 _MODULES = {
@@ -32,6 +33,7 @@ _MODULES = {
     "rwkv6-1.6b": "rwkv6_1_6b",
     "pixtral-12b": "pixtral_12b",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "joinml-oracle": "joinml_oracle",
     "joinml-embedder": "joinml_embedder",
 }

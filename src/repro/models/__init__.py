@@ -4,6 +4,7 @@ from .model import (  # noqa: F401
     forward,
     init_cache,
     init_params,
+    last_position_logits,
     loss_fn,
     prefill,
 )

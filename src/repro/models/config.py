@@ -27,10 +27,27 @@ class ModelConfig:
     window: int = 0                 # >0: sliding-window (local) attention
     causal: bool = True
 
+    # YaRN rope scaling (DeepSeek-V2): rope_factor 0 -> plain RoPE
+    rope_factor: float = 0.0
+    rope_original_max_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0   # 0 -> no softmax-scale correction
+
+    # latent attention (MLA, DeepSeek-V2): kv_lora_rank > 0 turns it on, and
+    # with it the dropless grouped expert layer (no capacity applies)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # MoE
     num_experts: int = 0
     num_experts_per_tok: int = 0
     moe_capacity_factor: float = 1.25
+    moe_d_ff: int = 0               # expert width (0 -> d_ff)
+    num_shared_experts: int = 0     # experts every token passes through
+    first_k_dense: int = 0          # leading dense layers before the MoE ones
 
     # encoder-decoder (whisper)
     encoder_layers: int = 0
@@ -96,24 +113,41 @@ class ModelConfig:
             return ("rwkv",)
         return ("dense",)
 
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
     def layer_types(self) -> list:
-        """Concrete per-layer block types of the decoder stack."""
+        """Concrete per-layer block types of the decoder stack: the leading
+        dense layers, then the family's pattern."""
         pat = self.pattern
-        return [pat[i % len(pat)] for i in range(self.num_layers)]
+        lead = min(self.first_k_dense, self.num_layers)
+        return ["dense"] * lead + [pat[i % len(pat)]
+                                   for i in range(self.num_layers - lead)]
+
+    def attention_params(self) -> int:
+        """Parameters of one attention block, its norms inside it (MLA's
+        latent norm) included."""
+        d, nq, nkv, hd = self.d_model, self.num_heads, self.num_kv_heads, self.head_dim
+        if not self.kv_lora_rank:
+            return d * hd * (nq + 2 * nkv) + nq * hd * d
+        r, dn = self.kv_lora_rank, self.qk_nope_head_dim
+        dr, dv = self.qk_rope_head_dim, self.v_head_dim
+        return (d * nq * (dn + dr) + d * (r + dr) + r
+                + r * nq * (dn + dv) + nq * dv * d)
 
     def param_count(self) -> int:
         """Analytic parameter count (used for roofline MODEL_FLOPS = 6ND)."""
         d, ff, v = self.d_model, self.d_ff, self.vocab_size
-        hd = self.head_dim
-        nq, nkv = self.num_heads, self.num_kv_heads
-        attn = d * hd * (nq + 2 * nkv) + nq * hd * d
+        attn = self.attention_params()
         dense_mlp = 3 * d * ff if self.act == "silu" or self.act == "geglu" else 2 * d * ff
         total = 0
         for t in self.layer_types():
             if t == "dense":
                 total += attn + dense_mlp + 2 * d
             elif t == "moe":
-                total += attn + self.num_experts * 3 * d * ff + d * self.num_experts + 2 * d
+                experts = self.num_experts + self.num_shared_experts
+                total += attn + experts * 3 * d * self.expert_d_ff + d * self.num_experts + 2 * d
             elif t == "attn":  # hybrid local-attention block
                 total += attn + 3 * d * ff + 2 * d
             elif t == "rec":   # RG-LRU block
@@ -134,9 +168,9 @@ class ModelConfig:
         """Active params per token (MoE: top-k experts only)."""
         if self.family != "moe":
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
-        inactive = (self.num_experts - self.num_experts_per_tok) * 3 * d * ff
-        return int(self.param_count() - self.num_layers * inactive)
+        inactive = ((self.num_experts - self.num_experts_per_tok)
+                    * 3 * self.d_model * self.expert_d_ff)
+        return int(self.param_count() - self.layer_types().count("moe") * inactive)
 
 
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
@@ -163,5 +197,9 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         attn_q_chunk=16,
         remat=False,
     )
+    if cfg.kv_lora_rank:
+        small.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                     v_head_dim=16, moe_d_ff=32,
+                     num_layers=min(cfg.first_k_dense, 1) + 1)
     small.update(overrides)
     return dataclasses.replace(cfg, **small)

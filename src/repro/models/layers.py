@@ -85,10 +85,36 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, head_dim); positions: (..., seq) int32."""
+def yarn_freqs(cfg: ModelConfig) -> np.ndarray:
+    """YaRN's inverse frequencies for the ``qk_rope_head_dim`` rotary dims
+    (DeepSeek-V2): the plain frequency f_i below the correction range,
+    f_i / rope_factor above it, and a linear ramp between."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    f = rope_freqs(dim, base)
+    if not cfg.rope_factor:
+        return f
+
+    def corr(rotations):
+        return dim * np.log(cfg.rope_original_max_len / (rotations * 2 * np.pi)) / (
+            2 * np.log(base))
+
+    low = max(int(np.floor(corr(cfg.rope_beta_fast))), 0)
+    high = min(int(np.ceil(corr(cfg.rope_beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return f * (1.0 - ramp) + f / cfg.rope_factor * ramp
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * np.log(factor) + 1.0 if factor > 1 and mscale else 1.0
+
+
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: (..., seq, head_dim); positions: (..., seq) int32.  ``freqs``
+    (head_dim / 2 inverse frequencies) defaults to plain RoPE's."""
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta), jnp.float32)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)
+    freqs = jnp.asarray(freqs, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -186,14 +212,25 @@ def chunked_attention(
         dt = x.dtype
         q, k, v = grad_cast(q, dt), grad_cast(k, dt), grad_cast(v, dt)
     scale = cfg.head_dim**-0.5
+    out = _attend(q, k, v, positions, kv_positions, causal, window, scale,
+                  cfg.attn_q_chunk)
+    out = shard_activation(out, ("batch", "seq", "heads", "head_dim"))
+    return out.reshape(b, s, -1) @ p["wo"]
 
-    qc = max(min(cfg.attn_q_chunk, s), 1)
+
+def _attend(q, k, v, positions, kv_positions, causal, window, scale, q_chunk):
+    """Attention of q (B, S, nq, dk) over k (B, T, nkv, dk) and v (B, T,
+    nkv, dv), scanned over q chunks so the peak score buffer is (B, H,
+    q_chunk, T) (the memory shape FlashAttention gives on TPU).  Returns
+    (B, S, nq, dv)."""
+    b, s, nq, dk = q.shape
+    qc = max(min(q_chunk, s), 1)
     n_chunks = (s + qc - 1) // qc
     pad = n_chunks * qc - s
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         positions = jnp.pad(positions, ((0, 0), (0, pad)))
-    qs = q.reshape(b, n_chunks, qc, cfg.num_heads, cfg.head_dim)
+    qs = q.reshape(b, n_chunks, qc, nq, dk)
     pos_chunks = positions.reshape(b, n_chunks, qc)
 
     def body(carry, inp):
@@ -215,10 +252,8 @@ def chunked_attention(
         _, outs = jax.lax.scan(
             body, (), (qs.swapaxes(0, 1), pos_chunks.swapaxes(0, 1))
         )
-    out = outs.swapaxes(0, 1).reshape(b, n_chunks * qc, cfg.num_heads, cfg.head_dim)
-    out = out[:, :s]
-    out = shard_activation(out, ("batch", "seq", "heads", "head_dim"))
-    return out.reshape(b, s, -1) @ p["wo"]
+    out = outs.swapaxes(0, 1).reshape(b, n_chunks * qc, nq, v.shape[-1])
+    return out[:, :s]
 
 
 def decode_attention(p, cfg: ModelConfig, x, cache_k, cache_v, position,
@@ -387,3 +422,132 @@ def moe_mlp(p, cfg: ModelConfig, x):
     out = jax.vmap(lambda o, tk, c: o.at[tk].add(c))(out, tok_sorted, contrib)
     out = shard_activation(out, ("data_group", None, "embed"))
     return out.reshape(b, s, d)
+
+
+# ----------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2 §2.1), prefill form without weight
+# absorption
+# ----------------------------------------------------------------------------
+
+def mla_params(key, cfg: ModelConfig):
+    dt = dtype_of(cfg)
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, h * (dn + dr)), dt),
+        "wkv_a": dense_init(ks[1], (d, r + dr), dt),
+        "ln_kv": jnp.zeros((r,), jnp.float32),
+        "wkv_b": dense_init(ks[2], (r, h * (dn + dv)), dt),
+        "wo": dense_init(ks[3], (h * dv, d), dt),
+    }
+
+
+def mla_attention(p, cfg: ModelConfig, x, positions):
+    """q = x Wq -> [q_nope, q_pe] per head; [c_kv, k_pe] = x Wkv_a, c_kv
+    RMS-normed, [k_nope, v] = c_kv Wkv_b per head; RoPE (YaRN frequencies,
+    half-split layout) on q_pe and on k_pe, one vector shared by all heads;
+    causal softmax of [q_nope, q_pe] . [k_nope, k_pe] x (dn + dr)^-0.5 x
+    m^2, where m = yarn_mscale(factor, mscale_all_dim).  YaRN's cos and sin
+    scale, mscale(factor, mscale) / mscale(factor, mscale_all_dim), is 1:
+    the published config sets the two equal."""
+    b, s, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = (x @ p["wq"]).reshape(b, s, h, dn + dr)
+    kv = x @ p["wkv_a"]
+    c = rms_norm(kv[..., :r], p["ln_kv"], cfg.norm_eps)
+    kvb = (c @ p["wkv_b"]).reshape(b, s, h, dn + dv)
+    freqs = yarn_freqs(cfg)
+    pos = positions[:, None, :]
+    q_pe = apply_rope(q[..., dn:].swapaxes(1, 2), pos, cfg.rope_theta, freqs)
+    k_pe = apply_rope(kv[:, None, :, r:], pos, cfg.rope_theta, freqs)
+    q = jnp.concatenate([q[..., :dn], q_pe.swapaxes(1, 2)], axis=-1)
+    k = jnp.concatenate(
+        [kvb[..., :dn], jnp.broadcast_to(k_pe.swapaxes(1, 2), (b, s, h, dr))],
+        axis=-1)
+    m = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    scale = (dn + dr) ** -0.5 * m * m
+    out = _attend(q, k, kvb[..., dn:], positions, positions, True, 0, scale,
+                  cfg.attn_q_chunk)
+    return out.reshape(b, s, h * dv) @ p["wo"]
+
+
+# ----------------------------------------------------------------------------
+# dropless experts (DeepSeekMoE, §2.2): every routed token is computed, by one
+# grouped matmul over the tokens sorted by expert
+# ----------------------------------------------------------------------------
+
+GMM_ROWS = 256      # rows per grouped-matmul tile (the kernel's m tile)
+
+
+def grouped_moe_params(key, cfg: ModelConfig):
+    """Router (d, E) and the routed experts' SwiGLU weights, each matrix a
+    leaf of its own laid out (E, fan_in, fan_out)."""
+    dt = dtype_of(cfg)
+    d, ff, e = cfg.d_model, cfg.expert_d_ff, cfg.num_experts
+    ks = jax.random.split(key, 4)
+    return {
+        "router": dense_init(ks[0], (d, e), dt),
+        "w_gate": dense_init(ks[1], (e, d, ff), dt),
+        "w_up": dense_init(ks[2], (e, d, ff), dt),
+        "w_down": dense_init(ks[3], (e, ff, d), dt),
+    }
+
+
+def _gmm(lhs, rhs, sizes):
+    """lhs (m, k) rows sorted by group, rhs (E, k, n), ``sizes`` (E,) rows
+    of each group: megablox's grouped matmul, the kernel named ``gmm`` in
+    the device trace.  Rows past ``sizes.sum()`` belong to no group and are
+    never written."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from repro.kernels import interpret_mode
+
+    k, n = rhs.shape[1:]
+    tiling = (GMM_ROWS, 512 if k % 512 == 0 else k, 1024 if n % 1024 == 0 else n)
+    return gmm(lhs, rhs, sizes, lhs.dtype, tiling, interpret=interpret_mode())
+
+
+def grouped_rows(sizes) -> int:
+    """Rows the grouped matmul computes for these group sizes (..., E):
+    each non-empty group's rows rounded out to whole ``GMM_ROWS`` tiles, a
+    tile shared by two groups computed once for each."""
+    sizes = np.asarray(sizes, np.int64)
+    ends = np.cumsum(sizes, axis=-1)
+    starts = ends - sizes
+    tiles = np.where(sizes > 0, -(-ends // GMM_ROWS) - starts // GMM_ROWS, 0)
+    return int(tiles.sum()) * GMM_ROWS
+
+
+def grouped_moe(p, cfg: ModelConfig, x, valid=None):
+    """x: (B, S, d) -> (routed experts' output (B, S, d), (E,) int32 rows
+    routed to each expert).
+
+    The gate is a float32 softmax over all experts; each token takes its
+    ``num_experts_per_tok`` largest (greedy), weighted by their gate values
+    as they are (not renormalised).  No token is dropped: the (token,
+    expert) routes are sorted by expert and every one is computed by one
+    grouped matmul per SwiGLU matrix.  Tokens where ``valid`` (B, S) is
+    False (padding) are routed to no expert and get 0."""
+    b, s, d = x.shape
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    t = b * s
+    xt = x.reshape(t, d)
+    gate = jax.nn.softmax(
+        jnp.dot(xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), axis=-1)
+    top_p, top_e = jax.lax.top_k(gate, k)                       # (T, k)
+    keys = top_e.reshape(t * k)
+    if valid is not None:
+        keys = jnp.where(jnp.repeat(valid.reshape(t), k), keys, e)
+    order = jnp.argsort(keys, stable=True)
+    sizes = jnp.bincount(keys, length=e + 1)[:e].astype(jnp.int32)
+    m = -(-t * k // GMM_ROWS) * GMM_ROWS
+    rows = jnp.pad(xt[order // k], ((0, m - t * k), (0, 0)))
+    act = jax.nn.silu(_gmm(rows, p["w_gate"], sizes)) * _gmm(rows, p["w_up"], sizes)
+    out = _gmm(act, p["w_down"], sizes)[: t * k]
+    out = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None], out, 0)
+    out = out[jnp.argsort(order)].reshape(t, k, d)
+    y = (out.astype(jnp.float32) * top_p[..., None]).sum(axis=1)
+    return y.astype(x.dtype).reshape(b, s, d), sizes

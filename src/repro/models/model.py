@@ -4,6 +4,8 @@ the HLO is O(1) in depth, with a uniform interface:
 
     init_params(cfg, key)                          -> params
     forward(cfg, params, batch)                    -> logits        (train/prefill)
+    last_position_logits(cfg, params, tokens, last, cols, valid)
+                                                   -> (logits, aux) (scoring)
     loss_fn(cfg, params, batch)                    -> scalar loss   (next-token CE)
     init_cache(cfg, batch, max_len)                -> cache
     decode_step(cfg, params, cache, tokens, pos)   -> (logits, cache)
@@ -27,6 +29,10 @@ from .layers import (
     decode_attention,
     dense_init,
     dtype_of,
+    grouped_moe,
+    grouped_moe_params,
+    mla_attention,
+    mla_params,
     mlp,
     mlp_params,
     moe_mlp,
@@ -139,7 +145,15 @@ def init_params(cfg: ModelConfig, key) -> dict:
         params["head"] = dense_init(keys[1], (cfg.d_model, cfg.vocab_size), dt)
 
     types = cfg.layer_types()
-    if cfg.family == "hybrid":
+    if cfg.kv_lora_rank:
+        lead = types.count("dense")
+        if lead:
+            params["dense_layers"] = jax.vmap(
+                lambda k: _mla_layer_params(k, cfg, "dense"))(
+                    jax.random.split(keys[2], lead))
+        params["layers"] = jax.vmap(lambda k: _mla_layer_params(k, cfg, "moe"))(
+            jax.random.split(keys[3], len(types) - lead))
+    elif cfg.family == "hybrid":
         pat = cfg.pattern
         nb = cfg.num_layers // len(pat)
         tail = types[nb * len(pat):]
@@ -225,10 +239,85 @@ def _run_stack(cfg, stacked, x, positions, kind, enc_out=None, enc_positions=Non
     return x
 
 
+# ----------------------------------------------------------------------------
+# the latent-attention family (DeepSeek-V2): leading dense layers, then the
+# MoE layers, each stack scanned
+# ----------------------------------------------------------------------------
+
+def _mla_layer_params(key, cfg: ModelConfig, kind: str):
+    d = cfg.d_model
+    ks = jax.random.split(key, 3)
+    p = {"ln1": jnp.zeros((d,), jnp.float32), "ln2": jnp.zeros((d,), jnp.float32),
+         "attn": mla_params(ks[0], cfg)}
+    if kind == "dense":
+        p["mlp"] = mlp_params(ks[1], cfg)
+        return p
+    p["moe"] = grouped_moe_params(ks[1], cfg)
+    if cfg.num_shared_experts:
+        p["shared"] = mlp_params(ks[2], cfg,
+                                 d_ff=cfg.num_shared_experts * cfg.expert_d_ff)
+    return p
+
+
+def _mla_layer(cfg: ModelConfig, kind: str, p, x, positions, valid):
+    """x + MLA(norm(x)), then + SwiGLU (dense) or + routed experts + the
+    shared experts (one SwiGLU of their summed width).  Returns (x, rows
+    routed to each expert, or None)."""
+    x = x + mla_attention(p["attn"], cfg, rms_norm(x, p["ln1"], cfg.norm_eps),
+                          positions)
+    h = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if kind == "dense":
+        return x + mlp(p["mlp"], cfg, h), None
+    routed, sizes = grouped_moe(p["moe"], cfg, h, valid)
+    if "shared" in p:
+        routed = routed + mlp(p["shared"], cfg, h)
+    return x + routed, sizes
+
+
+def _mla_stack(cfg: ModelConfig, params, tokens, valid=None):
+    """The residual stream after every layer, and the (MoE layers, E) rows
+    routed to each expert.  Tokens where ``valid`` is False are routed to no
+    expert."""
+    x, positions = _embed_inputs(cfg, params, {"tokens": tokens})
+    sizes = None
+    for stack, kind in (("dense_layers", "dense"), ("layers", "moe")):
+        if stack not in params:
+            continue
+
+        def body(h, p, kind=kind):
+            return _mla_layer(cfg, kind, p, h, positions, valid)
+
+        fn = jax.checkpoint(body, prevent_cse=False) if cfg.remat else body
+        with jax.named_scope("layers_scan"):
+            x, out = jax.lax.scan(fn, x, params[stack])
+        if kind == "moe":
+            sizes = out
+    return x, sizes
+
+
+def last_position_logits(cfg: ModelConfig, params, tokens, last, cols, valid):
+    """float32 logits of the vocabulary columns ``cols`` at each row's
+    ``last`` position (B, len(cols)), and the rows routed to each expert per
+    MoE layer: the scoring program, which applies the head at one position
+    and to the columns asked for only.  The latent-attention family only
+    (the dense family still scores through :func:`forward`)."""
+    if not cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.name} ({cfg.family}): last_position_logits serves the "
+            "latent-attention family")
+    x, sizes = _mla_stack(cfg, params, tokens, valid)
+    h = rms_norm(x[jnp.arange(x.shape[0]), last], params["ln_f"], cfg.norm_eps)
+    head = params["head"][:, jnp.asarray(cols)]
+    return jnp.dot(h, head, preferred_element_type=jnp.float32), sizes
+
+
 def forward(cfg: ModelConfig, params, batch) -> jax.Array:
     """Full-sequence forward -> logits (B, S_total, V)."""
     if cfg.family == "encdec":
         return _forward_encdec(cfg, params, batch)
+    if cfg.kv_lora_rank:
+        x, _ = _mla_stack(cfg, params, batch["tokens"])
+        return rms_norm(x, params["ln_f"], cfg.norm_eps) @ params["head"]
     x, positions = _embed_inputs(cfg, params, batch)
     kind = cfg.layer_types()[0]
     if cfg.family == "hybrid":
@@ -344,7 +433,15 @@ def loss_fn(cfg: ModelConfig, params, batch) -> jax.Array:
 # decode (serving)
 # ----------------------------------------------------------------------------
 
+def _no_latent_cache(cfg: ModelConfig, what: str) -> None:
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{what}: {cfg.name} ({cfg.family} family, latent attention) has no "
+            "decode cache; it serves only by scoring (last_position_logits)")
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
+    _no_latent_cache(cfg, "init_cache")
     dt = dtype_of(cfg)
     nkv, hd = cfg.num_kv_heads, cfg.head_dim
     if cfg.family == "ssm":
@@ -428,6 +525,7 @@ def decode_step(cfg: ModelConfig, params, cache: dict, tokens, position):
     occupant's stale KV entries.  Families without a positional cache only
     support the scalar form; their batcher gates admission instead.
     Returns (logits (B, V), cache)."""
+    _no_latent_cache(cfg, "decode_step")
     x = params["embed"][tokens]
     b = x.shape[0]
 
@@ -548,6 +646,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int):
     Returns (logits, cache).  Used by the serving layer; the dry-run lowers
     ``forward`` for prefill cells (the logits are what serving samples from).
     """
+    _no_latent_cache(cfg, "prefill")
     logits = forward(cfg, params, batch)
     cache = init_cache(cfg, batch["tokens"].shape[0], max_len)
     return logits, cache

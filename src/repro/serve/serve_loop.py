@@ -26,8 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.sharding import data_parallel, mesh_batch_shards
-from repro.models import decode_step, forward, init_cache
+from repro.models import decode_step, forward, init_cache, last_position_logits
 from repro.models.config import ModelConfig
+from repro.models.layers import grouped_rows
 from repro.obs.spans import span
 
 
@@ -45,13 +46,21 @@ def required_flops(cfg: ModelConfig, tokens: int, causal_pairs: int,
     needs, padding excluded: 2 x the active parameters outside the
     embeddings and norms per token, 4 x ``num_heads x head_dim`` per causal
     (query, key) pair in each attention layer, and the 2-column yes/no head
-    per pair (not the full-vocabulary head ``forward`` computes)."""
+    per pair (not the full-vocabulary head ``forward`` computes).  Latent
+    attention counts its query-key and value widths per pair, and its
+    latent norm among the norms."""
     d = cfg.d_model
     embed = cfg.vocab_size * d * (1 if cfg.tied_embeddings else 2)
-    matmul = cfg.active_param_count() - embed - 2 * d * cfg.num_layers
+    norms = (2 * d + cfg.kv_lora_rank) * cfg.num_layers
+    matmul = cfg.active_param_count() - embed - norms
     attn_layers = sum(t in ("dense", "moe", "attn") for t in cfg.layer_types())
+    if cfg.kv_lora_rank:
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        per_pair = 2 * cfg.num_heads * (qk + cfg.v_head_dim)
+    else:
+        per_pair = 4 * cfg.num_heads * cfg.head_dim
     return (2.0 * matmul * tokens
-            + 4.0 * attn_layers * cfg.num_heads * cfg.head_dim * causal_pairs
+            + 1.0 * attn_layers * per_pair * causal_pairs
             + 4.0 * d * pairs)
 
 
@@ -68,6 +77,15 @@ class PairScorer:
     real rows), so ``1 - tokens / token_slots`` is the padding share and
     :meth:`required_flops` the model work done.  Its spans: ``tokenize``, then per block ``scorer.pad``, ``scorer.forward``
     and ``scorer.fetch`` (waiting for the device's logits).
+
+    The latent-attention family (``cfg.kv_lora_rank``) scores through
+    :func:`~repro.models.last_position_logits`: the head at each row's last
+    position, over the yes and no columns only, with padding routed to no
+    expert.  It also counts ``routed_rows`` (real tokens x experts per token
+    x MoE layers), ``expert_rows`` (rows the grouped expert matmul computed,
+    tile rounding included) and ``expert_load_max`` (the largest expert's
+    rows, summed over layers and blocks); :meth:`counters` returns them with
+    the common four.
     """
 
     def __init__(self, cfg: ModelConfig, params, tokenize_pair: Callable,
@@ -84,14 +102,31 @@ class PairScorer:
         self.token_slots = 0
         self.tokens = 0
         self.causal_pairs = 0
+        self.moe_layers = cfg.layer_types().count("moe") if cfg.kv_lora_rank else 0
+        self.routed_rows = self.expert_rows = self.expert_load_max = 0
         self._count_lock = threading.Lock()   # score may run on several threads
+        self._side = threading.local()        # a block's expert group sizes
 
-        def fwd(p, b):
-            # [yes, no] logits at each row's last real position, gathered on
-            # the device so only (B, 2) values come back to the host
-            logits = forward(cfg, p, {"tokens": b["tokens"]})
-            last = logits[jnp.arange(logits.shape[0]), b["last"]]
-            return jnp.stack([last[:, yes_id], last[:, no_id]], axis=1)
+        # the scorer's program is ``jit_fwd`` in either form
+        if cfg.kv_lora_rank:
+            if mesh is not None:
+                raise NotImplementedError(
+                    f"{cfg.name}: the data-parallel scorer serves the dense family")
+
+            def fwd(p, b):
+                toks, last = b["tokens"], b["last"]
+                # pad columns after ``last`` and pad rows (token 0 first)
+                valid = ((jnp.arange(toks.shape[1])[None, :] <= last[:, None])
+                         & (toks[:, :1] != 0))
+                return last_position_logits(cfg, p, toks, last, (yes_id, no_id),
+                                            valid)
+        else:
+            def fwd(p, b):
+                # [yes, no] logits at each row's last real position, gathered on
+                # the device so only (B, 2) values come back to the host
+                logits = forward(cfg, p, {"tokens": b["tokens"]})
+                last = logits[jnp.arange(logits.shape[0]), b["last"]]
+                return jnp.stack([last[:, yes_id], last[:, no_id]], axis=1)
 
         if mesh is not None:
             shards = mesh_batch_shards(mesh)
@@ -113,8 +148,11 @@ class PairScorer:
         """Device (B, 2) [yes, no] logits of one padded block (``B`` =
         ``batch_size``); sharded over the batch axes under ``mesh``."""
         self.forward_batches += 1
-        return self._fwd(self.params, {"tokens": jnp.asarray(toks),
-                                       "last": jnp.asarray(last)})
+        out = self._fwd(self.params, {"tokens": jnp.asarray(toks),
+                                      "last": jnp.asarray(last)})
+        if self.moe_layers:
+            out, self._side.sizes = out
+        return out
 
     def encode(self, pairs: np.ndarray, pad_len: int) -> tuple:
         """Tokenize and pad ``pairs`` -> ((n, pad_len) tokens, (n,) last
@@ -126,6 +164,15 @@ class PairScorer:
         with self._count_lock:
             return required_flops(self.cfg, self.tokens, self.causal_pairs,
                                   self.pairs_scored)
+
+    def counters(self) -> dict:
+        """The counters by name: the common four, and the expert counters of
+        the latent-attention family."""
+        names = ["tokens", "token_slots", "causal_pairs", "pairs_scored"]
+        if self.moe_layers:
+            names += ["routed_rows", "expert_rows", "expert_load_max"]
+        with self._count_lock:
+            return {k: getattr(self, k) for k in names}
 
     def _tokenize(self, pairs: np.ndarray) -> list:
         return [
@@ -158,7 +205,7 @@ class PairScorer:
         pad_of = self._buckets[np.searchsorted(self._buckets, lens)]
         out = np.empty(n, np.float64)
         bs = self.batch_size
-        slots = 0
+        slots = expert_rows = load_max = 0
         for pad_len in np.unique(pad_of):
             sel = np.nonzero(pad_of == pad_len)[0]
             for s in range(0, len(sel), bs):
@@ -178,7 +225,15 @@ class PairScorer:
                 with span("scorer.fetch"):
                     lg = np.asarray(dev, np.float64)
                 out[idxs] = _stable_yes_no_prob(lg)[: len(idxs)]
+                if self.moe_layers:
+                    sizes = np.asarray(self._side.sizes)
+                    expert_rows += grouped_rows(sizes)
+                    load_max += int(sizes.max(axis=-1).sum())
         with self._count_lock:
+            self.routed_rows += (int(lens.sum()) * self.cfg.num_experts_per_tok
+                                 * self.moe_layers)
+            self.expert_rows += expert_rows
+            self.expert_load_max += load_max
             self.pairs_scored += n
             self.token_slots += slots
             self.tokens += int(lens.sum())

@@ -99,8 +99,8 @@ def test_cell_supported_matrix():
                 skipped += 1
                 assert shape == "long_500k"
                 assert not cfg.supports_long_context
-    assert total == 40
-    assert skipped == 8  # exactly the pure full-attention archs on long_500k
+    assert total == 4 * len(ARCHS) == 44
+    assert skipped == 9  # exactly the pure full-attention archs on long_500k
 
 
 def test_input_specs_no_allocation():

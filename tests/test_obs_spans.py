@@ -387,3 +387,65 @@ def test_instance_hooks_see_every_block(scorer):
     assert all(shape[0] == BATCH for shape, _ in blocks)
     assert sum(shape[0] * shape[1] for shape, _ in blocks) == s.token_slots
     np.testing.assert_allclose(out, scorer.score(pairs), atol=1e-6)
+
+
+def test_expert_counters_match_hand_count(scorer):
+    """A latent-attention MoE scorer counts every real token's routes in each
+    MoE layer, the grouped matmul's rows by whole tiles per expert, and the
+    largest expert's rows; ``counters()`` returns them with the common four,
+    and the required work is counted by hand."""
+    from repro.configs import get_smoke_config
+    from repro.models import init_params
+    from repro.models.layers import GMM_ROWS
+    from repro.serve.serve_loop import PairScorer
+
+    cfg = get_smoke_config("deepseek-v2-lite", vocab_size=scorer.cfg.vocab_size,
+                           num_layers=3)
+    s = PairScorer(cfg, init_params(cfg, jax.random.key(1)), scorer.tokenize_pair,
+                   scorer.yes_id, scorer.no_id, max_len=MAX_LEN, batch_size=BATCH)
+    blocks = []
+    forward = s.yes_no_logits
+
+    def hook_forward(toks, last):
+        out = forward(toks, last)
+        real = np.asarray(toks)[:, 0] != 0
+        blocks.append((np.asarray(s._side.sizes),
+                       int((np.asarray(last)[real] + 1).sum())))
+        return out
+
+    s.yes_no_logits = hook_forward
+    pairs, lens = _mixed_pairs(scorer)
+    s.score(pairs[:20])
+    s.score(pairs[20:])
+    k, moe_layers = cfg.num_experts_per_tok, 2
+    rows = load = 0
+    for sizes, real in blocks:
+        assert sizes.shape == (moe_layers, cfg.num_experts)
+        assert (sizes.sum(axis=1) == real * k).all()   # dropless, no padding
+        for layer in sizes:
+            start = 0
+            for n in layer.tolist():
+                if n:
+                    first, end = start // GMM_ROWS, -(-(start + n) // GMM_ROWS)
+                    rows += (end - first) * GMM_ROWS
+                start += n
+            load += int(layer.max())
+    tokens = int(lens.sum())
+    assert s.counters() == {
+        "tokens": tokens, "token_slots": s.token_slots,
+        "causal_pairs": int(sum(n * (n + 1) // 2 for n in lens)),
+        "pairs_scored": len(pairs), "routed_rows": tokens * k * moe_layers,
+        "expert_rows": rows, "expert_load_max": load}
+    # per token: three MLA blocks, the dense SwiGLU, and per MoE layer the
+    # router, the shared expert and k routed ones; per causal pair the
+    # query-key and value widths of each head; a 2-column head per pair
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv, ef = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                      cfg.moe_d_ff)
+    mla = d * h * (dn + dr) + d * (r + dr) + r * h * (dn + dv) + h * dv * d
+    moe = d * cfg.num_experts + (cfg.num_shared_experts + k) * 3 * d * ef
+    per_tok = 3 * mla + 3 * d * cfg.d_ff + moe_layers * moe
+    hand = (2 * per_tok * tokens
+            + 3 * 2 * h * (dn + dr + dv) * sum(int(n) * (int(n) + 1) // 2 for n in lens)
+            + 4 * d * len(pairs))
+    assert s.required_flops() == pytest.approx(hand, rel=1e-12)

@@ -10,7 +10,11 @@ lower, more VMEM than a kernel may use).  Covered:
   ``autotune.CANDIDATES`` — a candidate that stops compiling must leave that
   list;
 * the ``PairScorer`` forward at ``joinml-oracle``'s published widths
-  (B=32, L=48), from ``jax.eval_shape`` parameters.
+  (B=32, L=48), from ``jax.eval_shape`` parameters;
+* the ``PairScorer`` forward of ``deepseek-v2-lite`` at its published widths
+  with its dense layer 0 and one MoE layer (B=32, L=512): the grouped expert
+  kernel (``gmm``) compiled for the chip and the program within one chip's
+  memory.
 
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -108,3 +112,33 @@ def test_pair_scorer_forward_compiles_for_v5e(one_chip):
     out = compiled.out_info
     assert out.shape == (32, 2)
     assert compiled.memory_analysis() is not None
+
+
+def test_latent_moe_scorer_compiles_for_v5e(one_chip, monkeypatch):
+    import dataclasses
+    import re
+
+    import repro.kernels
+    from repro.configs import get_config
+    from repro.models import init_params
+    from repro.serve.serve_loop import PairScorer
+
+    # the kernel decides interpret mode from the backend, which is the CPU
+    # here; the described chip compiles the kernel itself
+    monkeypatch.setattr(repro.kernels, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), num_layers=2)
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), shapes)
+    scorer = PairScorer(cfg, None, None, yes_id=5, no_id=6, max_len=512,
+                        batch_size=32)
+    batch = {"tokens": _spec((32, 512), jnp.int32, one_chip),
+             "last": _spec((32,), jnp.int32, one_chip)}
+    compiled = scorer._fwd.lower(params, batch).compile()
+    logits, sizes = compiled.out_info
+    assert logits.shape == (32, 2) and sizes.shape == (1, 64)
+    kernels = re.findall(r"%(\w+)\.\d+ = \S+ custom-call\([^\n]*"
+                         r"custom_call_target=\"tpu_custom_call\"", compiled.as_text())
+    assert kernels and set(kernels) == {"gmm"}
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
