@@ -295,41 +295,57 @@ def _mla_stack(cfg: ModelConfig, params, tokens, valid=None):
     return x, sizes
 
 
-def last_position_logits(cfg: ModelConfig, params, tokens, last, cols, valid):
-    """float32 logits of the vocabulary columns ``cols`` at each row's
-    ``last`` position (B, len(cols)), and the rows routed to each expert per
-    MoE layer: the scoring program, which applies the head at one position
-    and to the columns asked for only.  The latent-attention family only
-    (the dense family still scores through :func:`forward`)."""
-    if not cfg.kv_lora_rank:
-        raise NotImplementedError(
-            f"{cfg.name} ({cfg.family}): last_position_logits serves the "
-            "latent-attention family")
-    x, sizes = _mla_stack(cfg, params, tokens, valid)
-    h = rms_norm(x[jnp.arange(x.shape[0]), last], params["ln_f"], cfg.norm_eps)
-    head = params["head"][:, jnp.asarray(cols)]
-    return jnp.dot(h, head, preferred_element_type=jnp.float32), sizes
-
-
-def forward(cfg: ModelConfig, params, batch) -> jax.Array:
-    """Full-sequence forward -> logits (B, S_total, V)."""
+def _residual(cfg: ModelConfig, params, batch, valid=None):
+    """The residual stream after the last layer, before the final norm
+    (B, S_total, d), and the (MoE layers, E) rows routed to each expert of
+    the latent-attention family (``None`` for the others).  ``valid`` marks
+    the tokens the experts see."""
     if cfg.family == "encdec":
-        return _forward_encdec(cfg, params, batch)
+        return _encdec_residual(cfg, params, batch), None
     if cfg.kv_lora_rank:
-        x, _ = _mla_stack(cfg, params, batch["tokens"])
-        return rms_norm(x, params["ln_f"], cfg.norm_eps) @ params["head"]
+        return _mla_stack(cfg, params, batch["tokens"], valid)
     x, positions = _embed_inputs(cfg, params, batch)
-    kind = cfg.layer_types()[0]
     if cfg.family == "hybrid":
         x = _forward_hybrid(cfg, params, x, positions)
     elif cfg.family == "ssm":
         x = _run_stack(cfg, params["layers"], x, positions, "rwkv",
                        init_state_fn=rwkv_state_init)
     else:
-        x = _run_stack(cfg, params["layers"], x, positions, kind)
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tied_embeddings else params["head"]
-    logits = x @ head
+        x = _run_stack(cfg, params["layers"], x, positions,
+                       cfg.layer_types()[0])
+    return x, None
+
+
+def _head(cfg: ModelConfig, params, cols=None):
+    """The (d, V) vocabulary head, or its columns ``cols`` only."""
+    if cols is None:
+        return params["embed"].T if cfg.tied_embeddings else params["head"]
+    cols = jnp.asarray(cols)
+    if cfg.tied_embeddings:
+        return params["embed"][cols].T
+    return params["head"][:, cols]
+
+
+def last_position_logits(cfg: ModelConfig, params, tokens, last, cols, valid):
+    """float32 logits of the vocabulary columns ``cols`` at each row's
+    ``last`` position (B, len(cols)), and the rows routed to each expert per
+    MoE layer (``None`` for a family without experts): the scoring program.
+    The final norm works per position, so the residual stream is gathered at
+    ``last`` first and the head applied to one position and to the columns
+    asked for only.  ``valid`` (B, L) routes padding to no expert; causal
+    attention already keeps a row's trailing padding out of ``last``."""
+    x, sizes = _residual(cfg, params, {"tokens": tokens}, valid)
+    h = rms_norm(x[jnp.arange(x.shape[0]), last], params["ln_f"], cfg.norm_eps)
+    return jnp.dot(h, _head(cfg, params, cols),
+                   preferred_element_type=jnp.float32), sizes
+
+
+def forward(cfg: ModelConfig, params, batch) -> jax.Array:
+    """Full-sequence forward -> logits (B, S_total, V)."""
+    x, _ = _residual(cfg, params, batch)
+    logits = rms_norm(x, params["ln_f"], cfg.norm_eps) @ _head(cfg, params)
+    if cfg.family == "encdec" or cfg.kv_lora_rank:
+        return logits  # these two families' logits carry no sharding constraint
     return shard_activation(logits, ("batch", "seq", "vocab"))
 
 
@@ -362,7 +378,7 @@ def _forward_hybrid(cfg: ModelConfig, params, x, positions):
     return x
 
 
-def _forward_encdec(cfg: ModelConfig, params, batch):
+def _encdec_residual(cfg: ModelConfig, params, batch):
     frames = batch["frames"]  # (B, T_enc, d) precomputed conv-frontend output
     b, t_enc, _ = frames.shape
     enc_x = frames.astype(dtype_of(cfg)) + _sinusoidal(t_enc, cfg.d_model).astype(
@@ -380,9 +396,7 @@ def _forward_encdec(cfg: ModelConfig, params, batch):
     pos = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     x = _run_stack(cfg, params["layers"], x, pos, "dec", enc_out=enc_x,
                    enc_positions=enc_pos)
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tied_embeddings else params["head"]
-    return x @ head
+    return x
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.sharding import data_parallel, mesh_batch_shards
-from repro.models import decode_step, forward, init_cache, last_position_logits
+from repro.models import decode_step, init_cache, last_position_logits
 from repro.models.config import ModelConfig
 from repro.models.layers import grouped_rows
 from repro.obs.spans import span
@@ -46,7 +46,7 @@ def required_flops(cfg: ModelConfig, tokens: int, causal_pairs: int,
     needs, padding excluded: 2 x the active parameters outside the
     embeddings and norms per token, 4 x ``num_heads x head_dim`` per causal
     (query, key) pair in each attention layer, and the 2-column yes/no head
-    per pair (not the full-vocabulary head ``forward`` computes).  Latent
+    per pair (the head the scorer applies).  Latent
     attention counts its query-key and value widths per pair, and its
     latent norm among the norms."""
     d = cfg.d_model
@@ -78,14 +78,15 @@ class PairScorer:
     :meth:`required_flops` the model work done.  Its spans: ``tokenize``, then per block ``scorer.pad``, ``scorer.forward``
     and ``scorer.fetch`` (waiting for the device's logits).
 
-    The latent-attention family (``cfg.kv_lora_rank``) scores through
-    :func:`~repro.models.last_position_logits`: the head at each row's last
-    position, over the yes and no columns only, with padding routed to no
-    expert.  It also counts ``routed_rows`` (real tokens x experts per token
-    x MoE layers), ``expert_rows`` (rows the grouped expert matmul computed,
-    tile rounding included) and ``expert_load_max`` (the largest expert's
-    rows, summed over layers and blocks); :meth:`counters` returns them with
-    the common four.
+    Every family scores through :func:`~repro.models.last_position_logits`
+    (the program ``jit_fwd``): the head at each row's last position, over
+    the yes and no columns only, with padding routed to no expert.  The
+    latent-attention family (``cfg.kv_lora_rank``) also counts
+    ``routed_rows`` (real tokens x experts per token x MoE layers),
+    ``expert_rows`` (rows the grouped expert matmul computed, tile rounding
+    included) and ``expert_load_max`` (the largest expert's rows, summed
+    over layers and blocks); :meth:`counters` returns them with the common
+    four.
     """
 
     def __init__(self, cfg: ModelConfig, params, tokenize_pair: Callable,
@@ -107,26 +108,20 @@ class PairScorer:
         self._count_lock = threading.Lock()   # score may run on several threads
         self._side = threading.local()        # a block's expert group sizes
 
-        # the scorer's program is ``jit_fwd`` in either form
-        if cfg.kv_lora_rank:
-            if mesh is not None:
-                raise NotImplementedError(
-                    f"{cfg.name}: the data-parallel scorer serves the dense family")
+        if cfg.kv_lora_rank and mesh is not None:
+            raise NotImplementedError(
+                f"{cfg.name}: the data-parallel scorer serves the dense family")
 
-            def fwd(p, b):
-                toks, last = b["tokens"], b["last"]
-                # pad columns after ``last`` and pad rows (token 0 first)
-                valid = ((jnp.arange(toks.shape[1])[None, :] <= last[:, None])
-                         & (toks[:, :1] != 0))
-                return last_position_logits(cfg, p, toks, last, (yes_id, no_id),
-                                            valid)
-        else:
-            def fwd(p, b):
-                # [yes, no] logits at each row's last real position, gathered on
-                # the device so only (B, 2) values come back to the host
-                logits = forward(cfg, p, {"tokens": b["tokens"]})
-                last = logits[jnp.arange(logits.shape[0]), b["last"]]
-                return jnp.stack([last[:, yes_id], last[:, no_id]], axis=1)
+        def fwd(p, b):
+            # [yes, no] logits at each row's last real position, computed and
+            # gathered on the device so only (B, 2) values come back
+            toks, last = b["tokens"], b["last"]
+            # pad columns after ``last`` and pad rows (token 0 first)
+            valid = ((jnp.arange(toks.shape[1])[None, :] <= last[:, None])
+                     & (toks[:, :1] != 0))
+            logits, sizes = last_position_logits(cfg, p, toks, last,
+                                                 (yes_id, no_id), valid)
+            return logits if sizes is None else (logits, sizes)
 
         if mesh is not None:
             shards = mesh_batch_shards(mesh)

@@ -10,7 +10,8 @@ lower, more VMEM than a kernel may use).  Covered:
   ``autotune.CANDIDATES`` — a candidate that stops compiling must leave that
   list;
 * the ``PairScorer`` forward at ``joinml-oracle``'s published widths
-  (B=32, L=48), from ``jax.eval_shape`` parameters;
+  (B=32, L=48 and L=512), from ``jax.eval_shape`` parameters, with no
+  full-vocabulary logits and its temporaries under 0.6 GB at L=512;
 * the ``PairScorer`` forward of ``deepseek-v2-lite`` at its published widths
   with its dense layer 0 and one MoE layer (B=32, L=512): the grouped expert
   kernel (``gmm``) compiled for the chip and the program within one chip's
@@ -104,14 +105,19 @@ def test_pair_scorer_forward_compiles_for_v5e(one_chip):
     cfg = get_config("joinml-oracle")
     shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
     params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), shapes)
-    scorer = PairScorer(cfg, None, None, yes_id=5, no_id=6, max_len=48,
+    scorer = PairScorer(cfg, None, None, yes_id=5, no_id=6, max_len=512,
                         batch_size=32)
-    batch = {"tokens": _spec((32, 48), jnp.int32, one_chip),
-             "last": _spec((32,), jnp.int32, one_chip)}
-    compiled = scorer._fwd.lower(params, batch).compile()
-    out = compiled.out_info
-    assert out.shape == (32, 2)
-    assert compiled.memory_analysis() is not None
+    for pad in (48, 512):
+        batch = {"tokens": _spec((32, pad), jnp.int32, one_chip),
+                 "last": _spec((32,), jnp.int32, one_chip)}
+        compiled = scorer._fwd.lower(params, batch).compile()
+        out = compiled.out_info
+        assert out.shape == (32, 2)
+        assert compiled.memory_analysis() is not None
+    # the head is applied at the last position only: no (B, L, V) logits,
+    # and the temporaries of a 512 bucket well under that array's 1.07 GB
+    assert f"[32,512,{cfg.vocab_size}]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 def test_latent_moe_scorer_compiles_for_v5e(one_chip, monkeypatch):
