@@ -1,8 +1,8 @@
 """Fig. 14 / Appendix A: CPU-phase latency decomposition of BAS (similarity,
 stratification, pilot, allocation, execution, resampling CI) — the speedup of
-the fused single-sweep stratification vs the paper's sort and vs the retired
-two-pass kernel schedule — and the dense-vs-streaming crossover sweep that
-calibrates the memory-aware dispatcher (``repro.core.dispatch``).
+the fused single-sweep stratification vs the paper's sort — and the
+dense-vs-streaming crossover sweep that calibrates the memory-aware
+dispatcher (``repro.core.dispatch``).
 
 Run via ``python -m benchmarks.run --only latency`` (``--full`` for
 paper-scale table sizes).  CI diffs the ``--json`` output against
@@ -38,27 +38,18 @@ def run(fast: bool = True, smoke: bool = False):
                         f"{t[phase] / total:.3f}"))
     rows.append(row("fig14_total", total, f"{total:.3f}s"))
 
-    # sort-based (paper) vs two-pass kernel vs fused single-sweep
-    # stratification at scale
+    # sort-based (paper) vs fused single-sweep stratification at scale
     w = pair_weights(ds.emb1, ds.emb2).reshape(-1)
     cfg = BASConfig()
     t0 = time.perf_counter()
     stratify_dense(w, 0.2, q.budget, cfg)
     dt_sort = time.perf_counter() - t0
     t0 = time.perf_counter()
-    two = stratify_streaming(ds.emb1, ds.emb2, 0.2, q.budget, cfg,
-                             use_kernel=True, use_sweep=False)
-    dt_two = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    one = stratify_streaming(ds.emb1, ds.emb2, 0.2, q.budget, cfg,
-                             use_kernel=True, use_sweep=True)
+    stratify_streaming(ds.emb1, ds.emb2, 0.2, q.budget, cfg, use_kernel=True)
     dt_sweep = time.perf_counter() - t0
-    assert (one.order == two.order).all(), "sweep strata diverged from two-pass"
     rows.append(row("fig14_stratify_sort", dt_sort, f"{dt_sort*1e3:.1f}ms"))
-    rows.append(row("fig14_stratify_twopass_kernel", dt_two,
-                    f"speedup_vs_sort_x={dt_sort / max(dt_two, 1e-9):.2f}"))
     rows.append(row("fig14_stratify_sweep_kernel", dt_sweep,
-                    f"sweep_vs_twopass_x={dt_two / max(dt_sweep, 1e-9):.2f}"))
+                    f"speedup_vs_sort_x={dt_sort / max(dt_sweep, 1e-9):.2f}"))
     rows.extend(walk_setup_rows(fast, smoke))
     rows.extend(crossover_sweep(fast, smoke))
     return rows
@@ -68,8 +59,9 @@ def walk_setup_rows(fast: bool = True, smoke: bool = False):
     """Walk-setup latency (per-edge row sums + chain total weight for the
     WWJ sampler), separated from sampling.
 
-    ``walk_setup_twopass`` is the retired schedule: two standalone f64
-    passes over the cross product after stratification.  The fused sweep
+    ``walk_setup_twopass`` is the standalone recomputation that
+    low-precision sweeps still take: two f64 passes over the cross product
+    after stratification.  The fused sweep
     emits the same statistics inline (one-pass chain statistics, see
     docs/kernels.md), so a cold fused query's walk setup is just a read of
     the sweep output (``walk_setup_fused_cold``, measured end-to-end inside

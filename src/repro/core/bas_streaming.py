@@ -12,11 +12,10 @@ Differences from the dense path (``bas.run_bas``):
   prefix-weight x last-edge pair weight, so the kernel's per-row ``scale``
   operand carries the prefix chain weight and nothing bigger than one block
   is materialised.  ``cfg.sweep_precision`` opts into the bf16/int8 MXU
-  fast path (tolerance-gated, see ``stratify.sweep_pass``); the fp32
-  default bins bit-identically to the retired two-pass schedule, and its
-  fused walk statistics (row sums / chain total, compensated f32) agree
-  with the f64 recomputation to ~1 ulp — so estimates match the two-pass
-  path to ~1e-7 relative, with zero extra passes over the product;
+  fast path (tolerance-gated, see ``stratify.sweep_pass``); at the fp32
+  default the fused walk statistics (row sums / chain total, compensated
+  f32) agree with the f64 recomputation to ~1 ulp, so walk setup takes
+  them with zero extra passes over the product;
 * the minimum sampling regime D_0 is sampled by **walk + rejection**: WWJ
   walk proposals from the full-space distribution
   p(t) = (1/N1) * prod_j w_j(t_j, t_{j+1}) / r_j(t_j)
@@ -104,7 +103,6 @@ def build_streaming_space(
     timings: dict,
     n_bins: int = 4096,
     use_kernel: Optional[bool] = None,
-    use_sweep: Optional[bool] = None,
     precision: Optional[str] = None,
     artifact=None,
     index_store=None,
@@ -118,8 +116,6 @@ def build_streaming_space(
     ``walk_sample`` span, summed into ``timings["walk_s"]``."""
     if use_kernel is None:
         use_kernel = cfg.use_kernel
-    if use_sweep is None:
-        use_sweep = cfg.use_sweep
     if precision is None:
         precision = cfg.sweep_precision
 
@@ -142,8 +138,7 @@ def build_streaming_space(
         index_hit = True
     strat = stratify_streaming_chain(
         embeddings, cfg.alpha, query.budget, cfg, n_bins=n_bins,
-        use_kernel=use_kernel, use_sweep=use_sweep, precision=precision,
-        artifact=artifact,
+        use_kernel=use_kernel, precision=precision, artifact=artifact,
     )
     k = strat.num_strata
     sizes = strat.stratum_sizes()
@@ -151,8 +146,7 @@ def build_streaming_space(
     timings["stratify_s"] = time.perf_counter() - t0
     # the opt-in low-precision sweep also hands its collected weights to the
     # samplers (HT stays exact: q is computed from the weights actually
-    # sampled with); the fp32 default recomputes them in f64 so estimates
-    # stay bit-identical to the two-pass schedule
+    # sampled with); the fp32 default recomputes them in f64
     lowp = (
         strat.sweep is not None and strat.sweep.precision != "fp32"
         and strat.order_weights is not None
@@ -162,9 +156,9 @@ def build_streaming_space(
     # Walk setup (row sums + chain total weight) consumes the statistics the
     # fused sweep emitted alongside the histogram — or, on a warm index,
     # hydrates them from the artifact — so no second pass over the cross
-    # product is ever launched here.  Only the two-pass baseline
-    # (use_sweep=False) and low-precision sweeps (which withhold their sums,
-    # see stratify.SweepInfo) fall back to the standalone recomputation.
+    # product is ever launched here.  Low-precision sweeps withhold their
+    # sums (see stratify.SweepInfo) and an empty blocking regime runs no
+    # sweep; only those take the standalone f64 recomputation.
     t0 = time.perf_counter()
     fused = strat.sweep is not None and strat.sweep.row_sums is not None
     if fused:
@@ -216,8 +210,7 @@ def build_streaming_space(
             tup = per_tup[i][pos]
         return StratumDraw(tup=tup, q=q, size=int(sizes[i]))
 
-    meta = {"path": "sweep" if strat.sweep is not None else "two-pass",
-            "walk_setup": "fused" if fused else "recompute"}
+    meta = {"path": "sweep", "walk_setup": "fused" if fused else "recompute"}
     if strat.sweep is not None:
         meta.update(
             kernel=strat.sweep.kernel, precision=strat.sweep.precision,
@@ -246,7 +239,6 @@ def run_bas_streaming(
     seed: int = 0,
     n_bins: int = 4096,
     use_kernel: Optional[bool] = None,
-    use_sweep: Optional[bool] = None,
     precision: Optional[str] = None,
     artifact=None,
     index_store=None,
@@ -275,8 +267,7 @@ def run_bas_streaming(
     with span("stratify"):
         space, extra = build_streaming_space(
             query, cfg, rng, timings, n_bins=n_bins, use_kernel=use_kernel,
-            use_sweep=use_sweep, precision=precision, artifact=artifact,
-            index_store=index_store,
+            precision=precision, artifact=artifact, index_store=index_store,
         )
     return run_stratified_pipeline(
         query, cfg, rng, space, {"mode": "bas_streaming", **extra},

@@ -109,7 +109,6 @@ def run_auto(
         dense_weight_bytes=footprint,
         max_dense_weight_bytes=cfg.max_dense_weight_bytes,
         n_tuples=query.spec.n_tuples,
-        sweep=cfg.use_sweep,
         sweep_precision=cfg.sweep_precision,
         index_store=index_store is not None,
     )

@@ -13,8 +13,9 @@ Two paths:
   histogram CDF quantile; collection reads the top-k candidates and rescans
   only the row blocks whose count tiles prove over-threshold mass — so the
   paper's O(N^2 log N^2) sort becomes ~one O(N^2) streaming pass, and the
-  cross product is never materialised.  (The two-pass histogram-then-collect
-  path is kept behind ``use_sweep=False`` as the bit-identical baseline.)
+  cross product is never materialised.  This is the only streaming
+  schedule; the full-scan ``collect_top``/``collect_top_chain`` without a
+  sweep is the exact reference the tests hold it to.
 
 k-way chains (``stratify_streaming_chain``): the chain weight factorises as
 prefix-weight x last-edge pair weight, so the sweep enumerates the chain's
@@ -25,8 +26,9 @@ histogram bins the geometric-mean weight W**(1/(k-1)) (a monotone transform —
 identical to the raw weight at k=2); the top-m threshold maps back as
 thr**(k-1).  Memory stays O(N + bins + block*Nk + m).
 
-Precision: the sweep runs fp32 by default (bit-identical to the two-pass
-path).  ``precision="bf16"``/``"int8"`` (see
+Precision: the sweep runs fp32 by default (its histogram bit-identical to
+the kernel's jnp reference, ``kernels/sim_sweep/ref.py``).
+``precision="bf16"``/``"int8"`` (see
 ``configs.joinml_embedder.EMBEDDING_PRECISIONS``) opt into the low-precision
 MXU fast path; the first row block is re-binned at fp32 and the sweep falls
 back to fp32 when the CDF deviation exceeds the configured tolerance.
@@ -481,80 +483,6 @@ def sweep_pass_chain(
     return info
 
 
-def weight_histogram(
-    e1: np.ndarray,
-    e2: np.ndarray,
-    n_bins: int = 4096,
-    exponent: float = 1.0,
-    floor: float = 1e-3,
-    block: int = 4096,
-    use_kernel: bool = False,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Two-pass baseline, pass 1: histogram of pair weights over the (never
-    materialised) cross product.  Returns (counts[n_bins], edges[n_bins+1])
-    with edges spanning [0, 1]."""
-    from .similarity import pair_weights  # local import to avoid cycle
-
-    if use_kernel:
-        from repro.kernels.sim_hist.ops import sim_hist
-
-        return sim_hist(e1, e2, n_bins, exponent, floor)
-
-    edges = np.linspace(0.0, 1.0, n_bins + 1)
-    counts = np.zeros(n_bins, np.int64)
-    n1 = e1.shape[0]
-    for s in range(0, n1, block):
-        w = pair_weights(e1[s : s + block], e2, exponent, floor)
-        c, _ = np.histogram(w, bins=edges)
-        counts += c
-    return counts, edges
-
-
-def chain_weight_histogram(
-    embeddings: list,
-    n_bins: int = 4096,
-    exponent: float = 1.0,
-    floor: float = 1e-3,
-    block: int = 4096,
-    use_kernel: bool = False,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Two-pass baseline, pass 1 for k-way chains: histogram of the
-    geometric-mean chain weight W(t)**(1/(k-1)), streamed over prefix blocks
-    (O(block * Nk) peak memory).  At k=2 this is ``weight_histogram``."""
-    from .similarity import pair_weights
-
-    k = len(embeddings)
-    if k == 2:
-        return weight_histogram(
-            embeddings[0], embeddings[1], n_bins, exponent, floor, block,
-            use_kernel,
-        )
-    root = 1.0 / (k - 1)
-    e_prev, e_last = embeddings[-2], embeddings[-1]
-    n_prefix = 1
-    for e in embeddings[:-1]:
-        n_prefix *= e.shape[0]
-    edges = np.linspace(0.0, 1.0, n_bins + 1)
-    counts = np.zeros(n_bins, np.int64)
-    if use_kernel:
-        from repro.kernels.sim_hist.ops import sim_hist
-    for s in range(0, n_prefix, block):
-        wp, i_last = _prefix_chain_weights(
-            embeddings, s, min(s + block, n_prefix), exponent, floor
-        )
-        if use_kernel:
-            counts += sim_hist(
-                e_prev[i_last], e_last, n_bins, exponent * root, floor,
-                scale=wp**root,
-            )[0]
-        else:
-            w = pair_weights(e_prev[i_last], e_last, exponent, floor)
-            v = (wp[:, None] * w) ** root
-            c, _ = np.histogram(v, bins=edges)
-            counts += c
-    return counts, edges
-
-
 def threshold_for_top_m(counts: np.ndarray, edges: np.ndarray, m: int) -> float:
     """Largest bin edge t such that #weights >= t is >= m (CDF from the top).
 
@@ -568,12 +496,6 @@ def threshold_for_top_m(counts: np.ndarray, edges: np.ndarray, m: int) -> float:
     if len(ok) == 0:
         return float(edges[0])
     return float(edges[ok[-1]])
-
-
-def _sim_topk(e1, e2, k):
-    from repro.kernels.sim_topk.ops import sim_topk
-
-    return sim_topk(e1, e2, k=k)
 
 
 def _collect_from_topk(e1, e2, vals, idx, valid, threshold, exponent, floor,
@@ -605,7 +527,9 @@ def _collect_from_topk(e1, e2, vals, idx, valid, threshold, exponent, floor,
         # saturate too, so go straight to the exact rescan
         retry_pays = len(saturated) <= n1 // 4
         if k2 > kk and retry_pays:
-            v2, i2, valid2 = _sim_topk(e1[saturated], e2, k2)
+            from repro.kernels.sim_topk.ops import sim_topk
+
+            v2, i2, valid2 = sim_topk(e1[saturated], e2, k=k2)
             w2 = weight_of_score(np.asarray(v2, np.float64), exponent, floor)
             keep2 = (w2 >= threshold) & valid2
             if v2.shape[1] < n2:
@@ -629,15 +553,6 @@ def _collect_from_topk(e1, e2, vals, idx, valid, threshold, exponent, floor,
     return np.concatenate(flat), np.concatenate(wts)
 
 
-def _collect_top_pairs_topk(e1, e2, threshold, exponent, floor, stats=None):
-    """Two-pass baseline: run sim_topk now, then collect (see
-    :func:`_collect_from_topk`)."""
-    vals, idx, valid = _sim_topk(e1, e2, k=min(TOPK_CANDIDATES, e2.shape[0]))
-    return _collect_from_topk(
-        e1, e2, vals, idx, valid, threshold, exponent, floor, stats=stats
-    )
-
-
 def collect_top(
     e1: np.ndarray,
     e2: np.ndarray,
@@ -646,7 +561,6 @@ def collect_top(
     exponent: float = 1.0,
     floor: float = 1e-3,
     block: int = 4096,
-    use_kernel: bool = False,
     sweep: Optional[SweepInfo] = None,
     return_weights: bool = False,
 ):
@@ -656,41 +570,31 @@ def collect_top(
     With a :class:`SweepInfo` the candidates come straight from the sweep's
     top-k output (no second kernel pass) and any rescan — truncated rows,
     or the whole collection on the numpy path — touches only the row
-    blocks whose count tiles show over-threshold mass."""
+    blocks whose count tiles show over-threshold mass.  Without one, every
+    row block is scanned (the exact reference)."""
     from .similarity import pair_weights
 
     n1, n2 = e1.shape[0], e2.shape[0]
-    stats = sweep.stats if sweep is not None else None
-    if m_cap < 16 * n1:
-        out = None
-        if sweep is not None and sweep.topk is not None:
-            vals, idx, valid = sweep.topk
-            out = _collect_from_topk(
-                e1, e2, vals, idx, valid, threshold, exponent, floor,
-                stats=stats,
-            )
-        elif use_kernel:
-            out = _collect_top_pairs_topk(e1, e2, threshold, exponent, floor,
-                                          stats=stats)
-        if out is not None:
-            idx, w = out
-            order = np.argsort(w)[::-1][:m_cap]
-            if return_weights:
-                return idx[order], w[order]
-            return idx[order]
-
-    idx_chunks, w_chunks = [], []
-    if sweep is not None:
-        starts, step = sweep.rescan_starts(threshold, n1)
+    if m_cap < 16 * n1 and sweep is not None and sweep.topk is not None:
+        vals, top_i, valid = sweep.topk
+        idx, w = _collect_from_topk(
+            e1, e2, vals, top_i, valid, threshold, exponent, floor,
+            stats=sweep.stats,
+        )
     else:
-        starts, step = list(range(0, n1, block)), block
-    for s in starts:
-        w = pair_weights(e1[s : s + step], e2, exponent, floor)
-        r, c = np.nonzero(w >= threshold)
-        idx_chunks.append(((r + s).astype(np.int64) * n2 + c))
-        w_chunks.append(w[r, c])
-    idx = np.concatenate(idx_chunks) if idx_chunks else np.empty(0, np.int64)
-    w = np.concatenate(w_chunks) if w_chunks else np.empty(0, np.float64)
+        idx_chunks, w_chunks = [], []
+        if sweep is not None:
+            starts, step = sweep.rescan_starts(threshold, n1)
+        else:
+            starts, step = list(range(0, n1, block)), block
+        for s in starts:
+            w = pair_weights(e1[s : s + step], e2, exponent, floor)
+            r, c = np.nonzero(w >= threshold)
+            idx_chunks.append(((r + s).astype(np.int64) * n2 + c))
+            w_chunks.append(w[r, c])
+        idx = (np.concatenate(idx_chunks) if idx_chunks
+               else np.empty(0, np.int64))
+        w = np.concatenate(w_chunks) if w_chunks else np.empty(0, np.float64)
     order = np.argsort(w)[::-1][:m_cap]
     if return_weights:
         return idx[order], w[order]
@@ -704,7 +608,6 @@ def collect_top_chain(
     exponent: float = 1.0,
     floor: float = 1e-3,
     block: int = 4096,
-    use_kernel: bool = False,
     sweep: Optional[SweepInfo] = None,
     return_weights: bool = False,
 ):
@@ -718,7 +621,7 @@ def collect_top_chain(
     if k == 2:
         return collect_top(
             embeddings[0], embeddings[1], threshold_root, m_cap, exponent,
-            floor, block, use_kernel, sweep=sweep, return_weights=return_weights,
+            floor, block, sweep=sweep, return_weights=return_weights,
         )
     thr_w = threshold_root ** (k - 1)  # back to raw chain-weight space
     e_prev, e_last = embeddings[-2], embeddings[-1]
@@ -754,7 +657,6 @@ def stratify_streaming_chain(
     cfg: BASConfig,
     n_bins: int = 4096,
     use_kernel: bool = False,
-    use_sweep: Optional[bool] = None,
     precision: Optional[str] = None,
     artifact=None,
 ) -> Stratification:
@@ -765,16 +667,14 @@ def stratify_streaming_chain(
     — the estimator stays unbiased because stratum membership is
     deterministic given the data.
 
-    ``use_sweep`` (default from ``cfg.use_sweep``) runs the fused
-    single-sweep path; ``use_sweep=False`` keeps the two-pass
-    histogram-then-collect baseline, which is bit-identical at fp32.
-    ``precision`` opts the sweep into the bf16/int8 fast path (default from
-    ``cfg.sweep_precision``), tolerance-gated via ``cfg.sweep_tolerance``.
+    One fused sweep (:func:`sweep_pass_chain`; the Pallas kernel, or the
+    numpy sweep with ``use_kernel=False``) gives the histogram and the
+    tiles and top-k that collection reads.  ``precision`` opts the sweep
+    into the bf16/int8 fast path (default from ``cfg.sweep_precision``),
+    tolerance-gated via ``cfg.sweep_tolerance``.
     ``artifact`` (:class:`repro.core.index.IndexArtifact`) hydrates a
     persisted sweep instead of computing one — threshold selection and
     collection run unchanged against the loaded tiles/top-k."""
-    if use_sweep is None:
-        use_sweep = cfg.use_sweep
     if precision is None:
         precision = cfg.sweep_precision
     n = 1
@@ -785,33 +685,19 @@ def stratify_streaming_chain(
     k = max(1, min(k, m)) if m > 0 else 0
     if m == 0:
         return Stratification(np.empty(0, np.int64), np.zeros(1, np.int64), n)
-    sweep = None
-    if artifact is not None:
-        sweep = sweep_pass_chain(
-            embeddings, n_bins, cfg.weight_exponent, cfg.weight_floor,
-            artifact=artifact,
-        )
-        counts, edges = sweep.counts, sweep.edges
-    elif use_sweep:
-        # collection only consults the top-k when the blocking regime is
-        # sparse per row (see collect_top); otherwise skip its epilogue cost
-        n1 = embeddings[0].shape[0]
-        k_top = TOPK_CANDIDATES if (len(embeddings) == 2 and m < 16 * n1) else 1
-        sweep = sweep_pass_chain(
-            embeddings, n_bins, cfg.weight_exponent, cfg.weight_floor,
-            use_kernel=use_kernel, precision=precision,
-            tolerance=cfg.sweep_tolerance, k_top=k_top,
-        )
-        counts, edges = sweep.counts, sweep.edges
-    else:
-        counts, edges = chain_weight_histogram(
-            embeddings, n_bins, cfg.weight_exponent, cfg.weight_floor,
-            use_kernel=use_kernel,
-        )
-    thr = threshold_for_top_m(counts, edges, m)
+    # collection only consults the top-k when the blocking regime is
+    # sparse per row (see collect_top); otherwise skip its epilogue cost
+    n1 = embeddings[0].shape[0]
+    k_top = TOPK_CANDIDATES if (len(embeddings) == 2 and m < 16 * n1) else 1
+    sweep = sweep_pass_chain(
+        embeddings, n_bins, cfg.weight_exponent, cfg.weight_floor,
+        use_kernel=use_kernel, precision=precision,
+        tolerance=cfg.sweep_tolerance, k_top=k_top, artifact=artifact,
+    )
+    thr = threshold_for_top_m(sweep.counts, sweep.edges, m)
     order, order_w = collect_top_chain(
         embeddings, thr, m, cfg.weight_exponent, cfg.weight_floor,
-        use_kernel=use_kernel, sweep=sweep, return_weights=True,
+        sweep=sweep, return_weights=True,
     )
     m_eff = len(order)
     k = max(1, min(k, m_eff))
@@ -830,12 +716,11 @@ def stratify_streaming(
     cfg: BASConfig,
     n_bins: int = 4096,
     use_kernel: bool = False,
-    use_sweep: Optional[bool] = None,
     precision: Optional[str] = None,
     artifact=None,
 ) -> Stratification:
     """Two-table wrapper of :func:`stratify_streaming_chain`."""
     return stratify_streaming_chain(
         [e1, e2], alpha, budget, cfg, n_bins=n_bins, use_kernel=use_kernel,
-        use_sweep=use_sweep, precision=precision, artifact=artifact,
+        precision=precision, artifact=artifact,
     )

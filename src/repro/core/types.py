@@ -108,14 +108,9 @@ class BASConfig:
                                   # chain-weight array; when that footprint
                                   # exceeds this cap, run_auto routes to the
                                   # streaming path (O(N + alpha*b) memory)
-    use_kernel: bool = True       # streaming stratification: use the fused
-                                  # Pallas kernels (falls back to blocked
-                                  # jnp/numpy when unavailable)
-    use_sweep: bool = True        # fuse the stratification passes into ONE
-                                  # sim_sweep kernel launch (histogram +
-                                  # top-k + per-block count tiles); False
-                                  # keeps the two-pass sim_hist + sim_topk
-                                  # schedule (bit-identical at fp32)
+    use_kernel: bool = True       # streaming stratification: the fused
+                                  # sim_sweep Pallas kernel; False runs the
+                                  # blocked numpy sweep
     sweep_precision: str = "fp32"  # opt-in low-precision sweep: "bf16"
                                   # (bf16 MXU inputs, f32 accumulation) or
                                   # "int8" (per-row-quantised embeddings,

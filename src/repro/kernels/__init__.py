@@ -1,5 +1,6 @@
-"""Pallas kernels of the stratification hot path (``sim_sweep`` and the
-two-pass ``sim_hist`` + ``sim_topk`` pair) and the model kernels."""
+"""Pallas kernels of the stratification hot path (the fused ``sim_sweep``
+pass, and ``sim_topk`` for the raised-k collection retry) and the model
+kernels."""
 
 
 def interpret_mode() -> bool:

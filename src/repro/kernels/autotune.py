@@ -1,6 +1,6 @@
 """Block-shape autotuner for the fused similarity kernels.
 
-The sim_sweep/sim_hist grids are parameterised by a (row-block, col-block)
+The sim_sweep grid is parameterised by a (row-block, col-block)
 schedule.  The historical defaults (256, 256) are sensible on one TPU
 generation but leave throughput on the table on others — and hardcoding a
 single shape makes per-accelerator perf gates (``bench_diff
@@ -125,12 +125,7 @@ def _time_candidate(op: str, m: int, n: int, d: int, precision: str,
     rng = np.random.default_rng(0)
     e1 = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
     e2 = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
-    if op == "sim_hist":
-        from .sim_hist.kernel import sim_hist_pallas
-
-        def run():
-            return sim_hist_pallas(e1, e2, bm=bm, bn=bn, interpret=False)
-    elif precision == "int8":
+    if precision == "int8":
         from .sim_sweep.kernel import sim_sweep_q_pallas
 
         q1, q2 = (jnp.clip(jnp.round(e * 32), -127, 127).astype(jnp.int8)

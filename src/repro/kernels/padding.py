@@ -1,10 +1,9 @@
 """Shared host-side padding helpers for the similarity kernels.
 
-``sim_hist`` and ``sim_sweep`` pad inputs to block multiples and subtract the
-padded-pair contributions from their histograms afterwards.  The two
-corrections MUST stay bit-identical — the single-sweep stratifier's
-fp32 bit-identity guarantee (sweep vs two-pass strata) rests on it — so both
-ops import these helpers instead of carrying copies.
+``sim_sweep`` and ``sim_topk`` pad inputs to block multiples.  The sweep
+then subtracts the padded-pair contributions from its count tiles
+(:func:`remove_pad_counts`), exactly — its fp32 histogram must equal the
+jnp reference on the unpadded inputs bit for bit.
 
 Block shapes follow the TPU's (8, 128) tiling: a row block is a power of two
 of at least 8 rows, and a column block — the lane dimension of the per-column

@@ -1,12 +1,10 @@
 """Single-sweep stratification kernel: histogram + top-k + per-block bins
 + compensated walk row-sums.
 
-The streaming stratifier (``repro.core.stratify``) used to pay the blocked
-``E1 @ E2^T`` product twice — once for the weight histogram (``sim_hist``)
-that sets the top-m threshold, once for the per-row top-k (``sim_topk``) that
-collects the blocking regime — and a third partial time when over-threshold
-rows had to be rescanned.  This kernel emits everything the stratifier needs
-from **one** pass over the product:
+The streaming stratifier (``repro.core.stratify``) needs a weight histogram
+that sets the top-m threshold, a per-row top-k that collects the blocking
+regime, and the walk sums; this kernel emits all of it from **one** pass
+over the blocked ``E1 @ E2^T`` product:
 
 * per-(row-block, bin) count tiles — the global histogram is their exact
   integer column sum, and the tiles tell the collector/sampler which row
@@ -20,11 +18,11 @@ from **one** pass over the product:
 
 The histogram half bins the *sampling weight* ``max(clip(s,0,1), floor) **
 exponent * scale`` (``scale`` is the per-left-row chain-prefix weight for
-k-way joins, exactly as in ``sim_hist``); the top-k half ranks the raw
-clipped score, which is monotone in the weight for any fixed row.  The
-walk-sum half applies the same clip/floor transform at an independent static
-power ``rs_exponent`` (chain sweeps bin the geometric-mean weight at
-``exponent * root`` but need the raw full-exponent edge weight in the sums).
+k-way joins); the top-k half ranks the raw clipped score, which is
+monotone in the weight for any fixed row.  The walk-sum half applies the
+same clip/floor transform at an independent static power ``rs_exponent``
+(chain sweeps bin the geometric-mean weight at ``exponent * root`` but need
+the raw full-exponent edge weight in the sums).
 
 Walk sums are accumulated with **compensated f32 arithmetic**: each (bm, bn)
 block is reduced by an error-free tree that carries (hi, lo) pairs through
@@ -36,7 +34,7 @@ column count or magnitude spread — naive sequential f32 accumulation loses
 several digits at these reduction lengths (see ``tests/test_chain_stats``).
 
 Precision paths (static ``compute_dtype``): fp32 feeds the MXU f32 inputs
-at ``HIGHEST`` precision (bit-identical to the sim_hist/sim_topk pair;
+at ``HIGHEST`` precision (histogram bit-identical to ``sim_sweep_ref``;
 Mosaic runs a default-precision f32 dot as one bf16 pass, ~5e-4 error on
 unit vectors on a v5e); bf16 feeds the MXU
 bf16 inputs with f32 accumulation; the int8 variant (``sim_sweep_q_pallas``)

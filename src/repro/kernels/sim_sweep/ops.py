@@ -6,12 +6,12 @@ the per-block tiles), per-(row-block, bin) count tiles for targeted rescans,
 the per-left-row top-k similar right rows for blocking-regime collection,
 and compensated per-row walk sums (the wandering-join proposal normaliser —
 see ``repro.core.bas_streaming``).  Padding corrections for the counts are
-the shared ``repro.kernels.padding`` helpers (the same ones ``sim_hist``
-applies, so the fp32 sweep stays bit-identical to the two-kernel path); the
-walk sums need none because the backward vector is zero in padded columns.
+the shared ``repro.kernels.padding`` helpers, exact, so the fp32 counts
+equal ``sim_sweep_ref`` on the unpadded inputs bit for bit; the walk sums
+need none because the backward vector is zero in padded columns.
 
-``precision`` selects the compute path: ``"fp32"`` (default, bit-identical
-to the sequential sim_hist + sim_topk pair), ``"bf16"`` (bf16 MXU inputs,
+``precision`` selects the compute path: ``"fp32"`` (default, histogram
+bit-identical to ``sim_sweep_ref``), ``"bf16"`` (bf16 MXU inputs,
 f32 accumulation), or ``"int8"`` (per-row symmetric quantisation via
 ``repro.core.similarity.quantize_rows_int8``, int32 MXU accumulation).
 

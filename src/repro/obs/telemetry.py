@@ -46,7 +46,7 @@ class StoreTelemetry:
 class StratifyTelemetry:
     """Which stratification path ran and its kernel/sweep statistics."""
 
-    path: str = ""           # dense-sort | sweep | two-pass | index
+    path: str = ""           # dense-sort | sweep | index
     extra: dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
@@ -68,7 +68,6 @@ class DispatchTelemetry:
     dense_weight_bytes: int = 0
     max_dense_weight_bytes: int = 0
     n_tuples: int = 0
-    sweep: bool = True
     sweep_precision: str = "fp32"
     index_store: bool = False
     extra: dict[str, Any] = dataclasses.field(default_factory=dict)

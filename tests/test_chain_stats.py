@@ -267,17 +267,19 @@ def test_warm_index_query_launches_zero_standalone_passes(tmp_path):
     assert r_warm.estimate == r_cold.estimate
 
 
-def test_two_pass_baseline_still_counts_passes():
-    """The counter itself works: the retired two-pass schedule
-    (use_sweep=False) launches both standalone passes."""
+def test_low_precision_sweep_still_counts_passes():
+    """The counter itself works: a bf16 sweep withholds its sums, so walk
+    setup launches both standalone passes."""
     from repro.core.bas_streaming import run_bas_streaming
 
     r, delta = _pass_delta(
-        lambda: run_bas_streaming(_small_query(), seed=0, use_sweep=False)
+        lambda: run_bas_streaming(_small_query(), seed=0, precision="bf16")
     )
     assert delta["edge_row_sums"] >= 1
     assert delta["chain_total_weight"] >= 1
-    assert r.telemetry.stratify.extra["walk_setup"] == "recompute"
+    extra = r.telemetry.stratify.extra
+    assert extra["precision"] == "bf16" and "lowp_fallback" not in extra
+    assert extra["walk_setup"] == "recompute"
 
 
 # ----------------------------------------------------------------------------

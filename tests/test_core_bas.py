@@ -120,7 +120,7 @@ def test_oracle_cache_free_requeries():
 
 def test_streaming_bas_matches_dense_and_scales():
     """The O(N1+N2+b) streaming path (histogram stratification via the
-    sim_hist kernel + walk/rejection D_0 sampling) agrees with the dense path
+    sim_sweep kernel + walk/rejection D_0 sampling) agrees with the dense path
     and stays within budget on a cross product we never materialise."""
     from repro.core import run_bas_streaming
 

@@ -10,7 +10,6 @@ from repro.core.stratify import (
     stratify_streaming,
     sweep_pass,
     threshold_for_top_m,
-    weight_histogram,
 )
 from repro.core.similarity import normalize, pair_weights
 from repro.core.types import BASConfig
@@ -60,7 +59,8 @@ def test_histogram_threshold_matches_exact():
     e1 = normalize(rng.standard_normal((200, 16)))
     e2 = normalize(rng.standard_normal((150, 16)))
     w = pair_weights(e1, e2).reshape(-1)
-    counts, edges = weight_histogram(e1, e2, n_bins=512)
+    sw = sweep_pass(e1, e2, n_bins=512)
+    counts, edges = sw.counts, sw.edges
     assert counts.sum() == len(w)
     m = 500
     thr = threshold_for_top_m(counts, edges, m)
@@ -132,32 +132,49 @@ def _strata_identical(a, b):
     )
 
 
+def _full_scan_strata(e1, e2, alpha, budget, n_bins=4096):
+    """Exact reference: every pair weight, its histogram, the CDF threshold,
+    then a descending sort of everything above it."""
+    w = pair_weights(e1, e2).reshape(-1)
+    edges = np.linspace(0.0, 1.0, n_bins + 1)
+    counts, _ = np.histogram(w, bins=edges)
+    m = min(int(round(alpha * budget)), len(w))
+    thr = threshold_for_top_m(counts.astype(np.int64), edges, m)
+    over = np.nonzero(w >= thr)[0]
+    order = over[np.argsort(w[over])[::-1]][:m]
+    k = max(1, min(auto_num_strata(alpha, budget, CFG), len(order)))
+    bounds = np.round(np.linspace(0, len(order), k + 1)).astype(np.int64)
+    return order, bounds, w
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
-def test_sweep_bit_identical_to_two_pass(use_kernel):
+def test_sweep_strata_match_full_scan(use_kernel):
     """The fused single-sweep stratification must produce *bit-identical*
-    strata to the retired two-pass (histogram then collect) schedule, on
-    both the numpy sweep and the Pallas kernel path."""
+    strata to a full scan of the product (every weight, np.histogram,
+    the CDF threshold, a descending sort), on both the numpy sweep and the
+    Pallas kernel path."""
     rng = np.random.default_rng(21)
     e1 = normalize(rng.standard_normal((130, 16)))
     e2 = normalize(rng.standard_normal((90, 16)))
-    one = stratify_streaming(e1, e2, 0.2, 2500, CFG, use_kernel=use_kernel,
-                             use_sweep=True)
-    two = stratify_streaming(e1, e2, 0.2, 2500, CFG, use_kernel=use_kernel,
-                             use_sweep=False)
-    assert _strata_identical(one, two)
-    assert one.sweep is not None and two.sweep is None
+    one = stratify_streaming(e1, e2, 0.2, 2500, CFG, use_kernel=use_kernel)
+    order, bounds, w = _full_scan_strata(e1, e2, 0.2, 2500)
+    np.testing.assert_array_equal(one.order, order)
+    np.testing.assert_array_equal(one.bounds, bounds)
+    assert one.n_total == len(w)
+    assert one.sweep is not None
     assert one.sweep.kernel == use_kernel
     assert one.order_weights is not None and len(one.order_weights) == len(one.order)
     # collected weights really are the strata weights, sorted descending
     assert np.all(np.diff(one.order_weights) <= 1e-12)
 
 
-def test_sweep_fallback_hist_matches_two_pass_hist():
+def test_sweep_fallback_hist_matches_full_histogram():
     rng = np.random.default_rng(22)
     e1 = normalize(rng.standard_normal((300, 8)))
     e2 = normalize(rng.standard_normal((50, 8)))
     sw = sweep_pass(e1, e2, n_bins=512, block=128)
-    counts, edges = weight_histogram(e1, e2, n_bins=512, block=128)
+    counts, _ = np.histogram(pair_weights(e1, e2),
+                             bins=np.linspace(0.0, 1.0, 513))
     np.testing.assert_array_equal(sw.counts, counts)
     np.testing.assert_array_equal(sw.block_counts.sum(axis=0), counts)
     assert sw.block_counts.shape == (3, 512) and sw.block_rows == 128
@@ -181,13 +198,13 @@ def test_sweep_block_skipping_is_conservative():
     assert sw.stats["blocks_rescanned"] < sw.stats["blocks_total"]
 
 
-@pytest.mark.parametrize("use_sweep", [False, True])
-def test_collect_top_beyond_candidate_cap(use_sweep):
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_collect_top_beyond_candidate_cap(use_kernel):
     """Regression for the hard k=64 top-k candidate cap: a few hot left
     rows with > 64 qualifying right rows each (amid cold rows, so the
-    top-k path engages) — the raised-k retry / targeted rescan must still
-    collect exactly the dense-scan result instead of silently dropping
-    the pairs beyond the cap."""
+    top-k path engages) — the kernel sweep's raised-k retry, and the numpy
+    sweep's flagged-block rescan, must still collect exactly the full-scan
+    result instead of silently dropping the pairs beyond the cap."""
     rng = np.random.default_rng(24)
     base = normalize(rng.standard_normal((1, 16)))
     hot = normalize(base + 0.01 * rng.standard_normal((4, 16)))
@@ -200,17 +217,19 @@ def test_collect_top_beyond_candidate_cap(use_sweep):
     thr = float((ws[-m_cap] + ws[-m_cap - 1]) / 2)  # off any exact weight
     assert (w[:4] >= thr).sum(axis=1).min() > 64  # hot rows exceed the cap
     assert m_cap < 16 * e1.shape[0]  # the top-k collection path engages
-    sw = sweep_pass(e1, e2, n_bins=512, use_kernel=True) if use_sweep else None
-    got = collect_top(e1, e2, thr, m_cap, use_kernel=True, sweep=sw)
-    want = collect_top(e1, e2, thr, m_cap, use_kernel=False)
+    sw = sweep_pass(e1, e2, n_bins=512, use_kernel=use_kernel)
+    got = collect_top(e1, e2, thr, m_cap, sweep=sw)
+    want = collect_top(e1, e2, thr, m_cap)
     assert set(got.tolist()) == set(want.tolist())
     assert len(got) == m_cap
-    if use_sweep:
+    if use_kernel:
         assert sw.stats.get("topk_retry_rows", 0) > 0
+    else:
+        assert sw.topk is None and "blocks_rescanned" in sw.stats
 
 
 # ----------------------------------------------------------------------------
-# end-to-end: sweep vs two-pass estimates, low-precision opt-in
+# end-to-end: kernel vs numpy sweep estimates, low-precision opt-in
 # ----------------------------------------------------------------------------
 
 def _small_query(budget=900):
@@ -222,23 +241,30 @@ def _small_query(budget=900):
                  budget=budget)
 
 
-def test_bas_streaming_sweep_estimates_match_two_pass():
-    """The fused sweep path draws the same samples as the two-pass schedule
-    (identical strata and oracle calls); its walk setup reads the sweep's
-    compensated f32 row sums instead of recomputing them in f64, so the
-    estimate agrees to the compensated-accumulation contract (~1 f32 ulp),
-    not bit-exactly (see kernels/sim_sweep: one-pass chain statistics)."""
+def test_bas_streaming_kernel_sweep_estimates_match_numpy_sweep():
+    """The kernel sweep draws the same samples as the numpy sweep (identical
+    strata and oracle calls); its walk setup reads the kernel's compensated
+    f32 row sums where the numpy sweep sums in f64, so the estimate agrees
+    to the compensated-accumulation contract (~1 f32 ulp), not bit-exactly
+    (see kernels/sim_sweep: one-pass chain statistics)."""
     from repro.core.bas_streaming import run_bas_streaming
+    from repro.core.stratify import stratify_streaming_chain
 
-    r1 = run_bas_streaming(_small_query(), seed=0, use_sweep=True)
-    r2 = run_bas_streaming(_small_query(), seed=0, use_sweep=False)
+    q = _small_query()
+    embs = [np.asarray(e, np.float32) for e in q.spec.embeddings]
+    s1, s2 = (stratify_streaming_chain(embs, CFG.alpha, q.budget, CFG,
+                                       use_kernel=uk) for uk in (True, False))
+    assert _strata_identical(s1, s2)
+    r1 = run_bas_streaming(q, seed=0, use_kernel=True)
+    r2 = run_bas_streaming(_small_query(), seed=0, use_kernel=False)
     assert r1.estimate == pytest.approx(r2.estimate, rel=1e-7)
     assert r1.ci.lo == pytest.approx(r2.ci.lo, rel=1e-6)
     assert r1.ci.hi == pytest.approx(r2.ci.hi, rel=1e-6)
     assert r1.oracle_calls == r2.oracle_calls
-    assert r1.detail["stratify"]["path"] == "sweep"
-    assert r1.detail["stratify"]["walk_setup"] == "fused"
-    assert "stratify" not in r2.detail or r2.detail["stratify"]["path"] == "two-pass"
+    for r, kernel in ((r1, True), (r2, False)):
+        assert r.telemetry.stratify.path == "sweep"
+        assert r.telemetry.stratify.extra["walk_setup"] == "fused"
+        assert r.telemetry.stratify.extra["kernel"] is kernel
 
 
 @pytest.mark.parametrize("precision", ["bf16", "int8"])
@@ -299,6 +325,5 @@ def test_sweep_config_is_plumbed_through_dispatch():
     cfg = dataclasses.replace(BASConfig(), max_dense_weight_bytes=0)
     res = dispatch.run_auto(q, cfg, seed=0)
     assert res.detail["dispatch"]["path"] == "streaming"
-    assert res.detail["dispatch"]["sweep"] is True
     assert res.detail["dispatch"]["sweep_precision"] == "fp32"
     assert res.detail["stratify"]["path"] == "sweep"

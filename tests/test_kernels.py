@@ -14,53 +14,65 @@ def rand_emb(rng, n, d, dtype):
 
 
 # ----------------------------------------------------------------------------
-# sim_hist
+# sim_sweep histogram half against its jnp reference
 # ----------------------------------------------------------------------------
 
 @pytest.mark.parametrize("m,n,d", [(64, 64, 16), (128, 64, 32), (256, 128, 8)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sim_hist_matches_ref(m, n, d, dtype):
-    from repro.kernels.sim_hist.kernel import sim_hist_pallas
-    from repro.kernels.sim_hist.ref import sim_hist_ref
+def test_sim_sweep_hist_matches_ref(m, n, d, dtype):
+    from repro.kernels.sim_sweep.kernel import sim_sweep_pallas
+    from repro.kernels.sim_sweep.ref import sim_sweep_ref
 
     rng = np.random.default_rng(0)
     e1, e2 = rand_emb(rng, m, d, dtype), rand_emb(rng, n, d, dtype)
-    n_bins = 256
-    got = sim_hist_pallas(e1, e2, n_bins=n_bins, bm=min(64, m), bn=min(64, n),
-                          interpret=True)
-    want = sim_hist_ref(e1, e2, n_bins=n_bins)
+    n_bins, bm = 256, min(64, m)
+    bc, _, _, _ = sim_sweep_pallas(e1, e2, n_bins=n_bins, bm=bm,
+                                   bn=min(64, n), compute_dtype=dtype,
+                                   interpret=True)
+    rbc, _, _, _ = sim_sweep_ref(e1, e2, n_bins=n_bins, bm=bm)
+    got, want = np.asarray(bc).sum(axis=0), np.asarray(rbc).sum(axis=0)
     assert int(got.sum()) == m * n
     # bf16 rounding can move boundary scores one bin; compare CDFs loosely
     np.testing.assert_allclose(
-        np.cumsum(np.asarray(got)), np.cumsum(np.asarray(want)),
-        atol=max(2, 0.01 * m * n),
+        np.cumsum(got), np.cumsum(want), atol=max(2, 0.01 * m * n),
     )
     if dtype == jnp.float32:
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(bc), np.asarray(rbc))
 
 
 @pytest.mark.parametrize("exponent", [0.5, 1.0, 2.0])
-def test_sim_hist_exponent(exponent):
-    from repro.kernels.sim_hist.kernel import sim_hist_pallas
-    from repro.kernels.sim_hist.ref import sim_hist_ref
+def test_sim_sweep_hist_exponent(exponent):
+    from repro.kernels.sim_sweep.kernel import sim_sweep_pallas
+    from repro.kernels.sim_sweep.ref import sim_sweep_ref
 
     rng = np.random.default_rng(1)
     e1, e2 = rand_emb(rng, 64, 16, jnp.float32), rand_emb(rng, 64, 16, jnp.float32)
-    got = sim_hist_pallas(e1, e2, n_bins=128, exponent=exponent, bm=64, bn=64,
-                          interpret=True)
-    want = sim_hist_ref(e1, e2, n_bins=128, exponent=exponent)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bc, _, _, _ = sim_sweep_pallas(e1, e2, n_bins=128, exponent=exponent,
+                                   bm=64, bn=64, interpret=True)
+    rbc, _, _, _ = sim_sweep_ref(e1, e2, n_bins=128, exponent=exponent, bm=64)
+    np.testing.assert_array_equal(np.asarray(bc), np.asarray(rbc))
 
 
-def test_sim_hist_ops_padding():
-    from repro.kernels.sim_hist import sim_hist
+def test_sim_sweep_ops_padding():
+    """The op pads 100 x 70 to block multiples and removes the padded
+    pairs' counts exactly: the result is the reference's on the unpadded
+    inputs."""
+    from repro.kernels.sim_sweep import sim_sweep
+    from repro.kernels.sim_sweep.ref import sim_sweep_ref
 
     rng = np.random.default_rng(2)
     e1 = normalize(rng.standard_normal((100, 16)))   # not a block multiple
     e2 = normalize(rng.standard_normal((70, 16)))
-    counts, edges = sim_hist(e1, e2, n_bins=256)
-    assert counts.sum() == 100 * 70
-    assert (counts >= 0).all()
+    sw = sim_sweep(e1, e2, n_bins=256, k=8)
+    assert sw.counts.sum() == 100 * 70
+    assert (sw.counts >= 0).all() and (sw.block_counts >= 0).all()
+    np.testing.assert_array_equal(sw.block_counts.sum(axis=0), sw.counts)
+    rbc, rvals, _, rrs = sim_sweep_ref(jnp.asarray(e1), jnp.asarray(e2),
+                                       n_bins=256, k=8)
+    np.testing.assert_array_equal(sw.counts, np.asarray(rbc).sum(axis=0))
+    np.testing.assert_allclose(sw.vals, np.asarray(rvals), atol=1e-6)
+    np.testing.assert_allclose(sw.row_sums, np.asarray(rrs)[:, 0], rtol=1e-6)
+    assert sw.valid.all()
 
 
 # ----------------------------------------------------------------------------
@@ -104,21 +116,22 @@ def test_sim_topk_ops_padding_and_validity():
 
 @pytest.mark.parametrize("m,n,d,k", [(64, 64, 16, 4), (128, 64, 32, 8),
                                      (100, 70, 16, 8)])
-def test_sim_sweep_bit_identical_to_two_kernel_path(m, n, d, k):
-    """The fused sweep must reproduce the sequential sim_hist + sim_topk
-    outputs bit-for-bit at fp32, and its count tiles must column-sum to the
-    global histogram exactly."""
-    from repro.kernels.sim_hist import sim_hist
+def test_sim_sweep_ops_matches_ref_and_sim_topk(m, n, d, k):
+    """At fp32 the op's histogram equals the jnp reference's bit for bit,
+    its count tiles column-sum to it exactly, and its top-k equals the
+    standalone ``sim_topk`` kernel's (which the raised-k retry runs)."""
     from repro.kernels.sim_sweep import sim_sweep
+    from repro.kernels.sim_sweep.ref import sim_sweep_ref
     from repro.kernels.sim_topk import sim_topk
 
     rng = np.random.default_rng(10)
     e1 = normalize(rng.standard_normal((m, d)))
     e2 = normalize(rng.standard_normal((n, d)))
     sw = sim_sweep(e1, e2, n_bins=256, k=k)
-    counts, edges = sim_hist(e1, e2, n_bins=256)
+    rbc, _, _, _ = sim_sweep_ref(jnp.asarray(e1), jnp.asarray(e2),
+                                 n_bins=256, k=k)
     vals, idx, valid = sim_topk(e1, e2, k=k)
-    np.testing.assert_array_equal(sw.counts, counts)
+    np.testing.assert_array_equal(sw.counts, np.asarray(rbc).sum(axis=0))
     np.testing.assert_array_equal(sw.vals, vals)
     np.testing.assert_array_equal(sw.idx, idx)
     np.testing.assert_array_equal(sw.valid, valid)
@@ -126,19 +139,20 @@ def test_sim_sweep_bit_identical_to_two_kernel_path(m, n, d, k):
     assert int(sw.counts.sum()) == m * n
 
 
-def test_sim_sweep_scale_matches_sim_hist():
-    """The per-row scale operand (k-way chain-prefix weights) must bin
-    identically to sim_hist's."""
-    from repro.kernels.sim_hist import sim_hist
+def test_sim_sweep_scale_matches_ref():
+    """The per-row scale operand (k-way chain-prefix weights) bins exactly
+    as the jnp reference does."""
     from repro.kernels.sim_sweep import sim_sweep
+    from repro.kernels.sim_sweep.ref import sim_sweep_ref
 
     rng = np.random.default_rng(11)
     e1 = normalize(rng.standard_normal((96, 16)))
     e2 = normalize(rng.standard_normal((80, 16)))
     scale = rng.random(96).astype(np.float32)
     sw = sim_sweep(e1, e2, n_bins=128, exponent=0.5, scale=scale)
-    counts, _ = sim_hist(e1, e2, n_bins=128, exponent=0.5, scale=scale)
-    np.testing.assert_array_equal(sw.counts, counts)
+    rbc, _, _, _ = sim_sweep_ref(jnp.asarray(e1), jnp.asarray(e2), n_bins=128,
+                                 exponent=0.5, scale=jnp.asarray(scale))
+    np.testing.assert_array_equal(sw.counts, np.asarray(rbc).sum(axis=0))
 
 
 def test_sim_sweep_matches_ref():

@@ -178,7 +178,6 @@ _LEGACY_DETAIL = {
         "dense_weight_bytes": 1024,
         "max_dense_weight_bytes": 4096,
         "n_tuples": 10000,
-        "sweep": True,
         "sweep_precision": "bf16",
         "index_store": True,
     },
